@@ -1,4 +1,5 @@
-"""Shared benchmark utilities: timing, CSV rows, result persistence."""
+"""Shared benchmark utilities: timing, CSV rows, result persistence, and
+the persistent compilation cache of the benchmark and smoke entry points."""
 from __future__ import annotations
 
 import json
@@ -6,6 +7,26 @@ import os
 import time
 
 RESULTS_DIR = os.environ.get("REPRO_RESULTS", "results/bench")
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX takes the directory from
+    it and nothing is configured here. Otherwise the cache lives at the
+    fixed ``<repo>/.jax_cache`` (git-ignored): the path is part of what the
+    cache is keyed on, so it must not move between runs. Entry points call
+    this before their first compile; library code never does.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def save_rows(name: str, rows):

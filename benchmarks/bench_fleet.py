@@ -18,7 +18,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.fleet.plan import (
     FleetSpec,
@@ -28,7 +27,7 @@ from repro.fleet.plan import (
     plan_fleet_reference,
 )
 
-from ._util import save_rows, write_bench_artifact
+from ._util import save_rows, use_compile_cache, write_bench_artifact
 
 
 def run(
@@ -46,7 +45,7 @@ def run(
     # Stack the fleet and place the demand matrix ONCE, so the timed loop
     # measures pure batched planning — not per-call Python stacking or the
     # host-to-device transfer of the (N, T) demand.
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.fleet.stack(jnp.float64)
         demand = jax.block_until_ready(jnp.asarray(sc.demand, jnp.float64))
     hpm = sc.fleet.hours_per_month
@@ -100,6 +99,7 @@ def run(
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--links", type=int, default=128)
     ap.add_argument("--horizon", type=int, default=8760)

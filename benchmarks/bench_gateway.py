@@ -54,7 +54,7 @@ from repro.fleet.plan import build_fleet_scenario
 from repro.fleet.stream import FleetRuntime, RuntimeConfig
 from repro.gateway import FleetGateway, GatewayConfig, TenantSpec
 
-from ._util import save_rows, write_bench_artifact
+from ._util import save_rows, use_compile_cache, write_bench_artifact
 from .bench_runtime import _gc_paused
 
 STEP_FIELDS = ("x", "state", "r_vpn", "r_cci", "vpn_cost", "cci_cost", "cost")
@@ -218,6 +218,7 @@ def run(n_tenants: int = 256, n_links: int = 32, ticks: int = 400, *,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tenants", type=int, default=256)
     ap.add_argument("--links", type=int, default=32)

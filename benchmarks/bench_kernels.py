@@ -38,7 +38,7 @@ from repro.kernels.tiered_cost import (
     tiered_cost_scan_ref,
 )
 
-from ._util import save_rows, write_bench_artifact
+from ._util import save_rows, use_compile_cache, write_bench_artifact
 
 
 def _operands(n_links: int, horizon: int, seed: int):
@@ -148,6 +148,7 @@ def run(n_links: int = 128, horizon: int = 8704, *, repeats: int = 5, seed: int 
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--links", type=int, default=128)
     ap.add_argument("--horizon", type=int, default=8704)

@@ -28,7 +28,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.fleet.plan import (
     FAMILIES,
@@ -41,7 +40,7 @@ from repro.fleet.plan import (
     plan_topology,
 )
 
-from ._util import save_rows, write_bench_artifact
+from ._util import save_rows, use_compile_cache, write_bench_artifact
 
 
 def _timed_plan(arrays, demand, hpm, policy, repeats: int) -> tuple:
@@ -85,7 +84,7 @@ def run(
             seed=seed + k,
         )
         routing = optimize_routing(sc.topo, sc.demand)
-        with enable_x64():
+        with jax.enable_x64():
             arrays = sc.topo.stack(routing, jnp.float64)
             demand = jax.block_until_ready(jnp.asarray(sc.demand, jnp.float64))
         hpm = sc.topo.hours_per_month
@@ -161,6 +160,7 @@ def run(
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--pairs", type=int, default=48)
     ap.add_argument("--horizon", type=int, default=8760)

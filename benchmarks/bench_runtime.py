@@ -65,7 +65,7 @@ from repro.fleet.plan import (
 )
 from repro.fleet.stream import FleetRuntime, streaming_forecast_policy
 
-from ._util import save_rows, write_bench_artifact
+from ._util import save_rows, use_compile_cache, write_bench_artifact
 
 
 @contextlib.contextmanager
@@ -185,9 +185,8 @@ def run(n_links: int = 1024, ticks: int = 3000, *, history: int = 600, seed: int
 
     # Forecast-gated live mode: SSM state carried through the jitted step.
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.fleet.stack(jnp.float64)
     t0 = time.perf_counter()
     pol, fc = streaming_forecast_policy(
@@ -297,6 +296,7 @@ def run_ksweep(n_links: int = 2048, ticks: int = 3000, *, seed: int = 0,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--links", type=int, default=2048)
     ap.add_argument("--ticks", type=int, default=3000)

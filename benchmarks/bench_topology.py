@@ -26,7 +26,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.fleet.plan import (
     build_multicast_scenario,
@@ -38,7 +37,7 @@ from repro.fleet.plan import (
     plan_topology_reference,
 )
 
-from ._util import save_rows, write_bench_artifact
+from ._util import save_rows, use_compile_cache, write_bench_artifact
 
 
 def _multihop_smoke(repeats: int):
@@ -53,7 +52,7 @@ def _multihop_smoke(repeats: int):
         "relay scenario failed to take the relay path"
     )
     hpm = rsc.topo.hours_per_month
-    with enable_x64():
+    with jax.enable_x64():
         arrays = rsc.topo.stack(routing, jnp.float64)
         demand = jax.block_until_ready(jnp.asarray(rsc.demand, jnp.float64))
     plan = plan_topology(arrays, demand, hours_per_month=hpm)
@@ -105,7 +104,7 @@ def run(
 
     # Stack + place ONCE so the timed loop measures pure routed planning
     # (the routing matrix is an operand — re-routing would reuse the jit).
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(routing, jnp.float64)
         demand = jax.block_until_ready(jnp.asarray(sc.demand, jnp.float64))
     hpm = sc.topo.hours_per_month
@@ -198,6 +197,7 @@ def run(
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--pairs", type=int, default=96)
     ap.add_argument("--horizon", type=int, default=8760)

@@ -27,7 +27,7 @@ from . import (
     bench_sensitivity,
     bench_topology,
 )
-from ._util import fmt_csv, timed
+from ._util import fmt_csv, timed, use_compile_cache
 
 FAST = os.environ.get("REPRO_BENCH_FAST", "0") == "1"
 
@@ -68,6 +68,7 @@ BENCHES = [
 
 
 def main() -> None:
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name, fn in BENCHES:

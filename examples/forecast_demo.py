@@ -13,8 +13,8 @@ Run:  PYTHONPATH=src python examples/forecast_demo.py
 """
 import numpy as np
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.fleet.plan import (
     build_topology_report,
@@ -40,7 +40,7 @@ def main() -> None:
         seed=7,
     )
     routing = optimize_routing(sc.topo, sc.demand)
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(routing, jnp.float64)
     hpm = sc.topo.hours_per_month
     print(

@@ -22,6 +22,7 @@ hours (the `reroute()` contract).
 
 Run:  PYTHONPATH=src python examples/reroute_demo.py
 """
+import jax
 import numpy as np
 
 from repro.fleet.plan import (
@@ -83,9 +84,8 @@ def main() -> None:
     # The reroute() contract: the streamed decisions equal an offline replay
     # that applies the same routings at the same hours, bit for bit.
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(r0, jnp.float64)
     schedule = [(0, r0)] + [(t, r_new) for t, _, r_new in swaps]
     replay = replay_plan_topology(

@@ -54,11 +54,14 @@ class TieredRate:
         """$ cost of moving cumulative volume from start_gb to start_gb+added_gb."""
         if added_gb <= 0:
             return 0.0
-        lo, total = float(start_gb), 0.0
-        hi = lo + float(added_gb)
+        lo, d, total = float(start_gb), float(added_gb), 0.0
+        hi = lo + d
         prev_bound = 0.0
         for bound, rate in zip(self.bounds_gb, self.rates):
-            seg = max(0.0, min(hi, bound) - max(lo, prev_bound))
+            # min(hi, bound) - max(lo, prev_bound), without forming hi
+            # (see repro.core.costmodel.tier_segment).
+            seg = max(0.0, min(d, bound - prev_bound, bound - lo,
+                               d - (prev_bound - lo)))
             total += seg * rate
             prev_bound = bound
             if bound >= hi:

@@ -40,7 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .costmodel import HourlyCosts, hourly_cost_series
+from .costmodel import HourlyCosts, hourly_cost_series, prefix_sum
 from .pricing import CostParams
 
 OFF, WAITING, ON = 0, 1, 2
@@ -179,7 +179,7 @@ def window_sums(hourly: jax.Array, h) -> jax.Array:
     acc = jnp.result_type(float)
     v = hourly.astype(acc)
     T = v.shape[0]
-    pref = jnp.concatenate([jnp.zeros(1, acc), jnp.cumsum(v)])
+    pref = jnp.concatenate([jnp.zeros(1, acc), prefix_sum(v)])
     t_idx = jnp.arange(T)
     lo = jnp.maximum(0, t_idx - h)
     return pref[t_idx] - pref[lo]

@@ -25,7 +25,6 @@ import re
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 INT8_MAX = 127.0
@@ -95,12 +94,12 @@ def sync_grads(grads, mesh, *, mode: str = "direct", err_state=None):
         return outs, errs
 
     err_in = err_state if use_err else jax.tree.map(lambda g: jnp.zeros((), jnp.float32), grads)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     outs, errs = mapped(grads, err_in)
     return outs, (errs if use_err else None)

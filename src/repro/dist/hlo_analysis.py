@@ -4,7 +4,9 @@
 reports unrolled-loop flops inconsistently; this walker parses the module
 text directly so the roofline benches get one deterministic number:
 
-* ``dot`` flops are exact: 2 x |output| x contracted extent;
+* ``dot`` flops are exact: 2 x |output| x contracted extent, with the lhs
+  shape read inline where the text prints it and otherwise resolved from the
+  instruction that defines the operand (newer XLA prints bare operand names);
 * ``while`` bodies multiply by the trip count (XLA annotates compiled loops
   with ``backend_config={"known_trip_count":{"n":...}}``; a constant-bound
   ``compare(LT)`` condition is the fallback);
@@ -23,6 +25,8 @@ _TRIP_RE = re.compile(r'known_trip_count[":{\s]+n["\s:]+"?(\d+)')
 _CALLED_RE = re.compile(r"(?:body|to_apply|calls|condition|branch_computations)="
                         r"[({]?%?([\w.\-]+)")
 _DOT_CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
+_DEF_RE = re.compile(r"^(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*[a-z0-9]+\[([0-9,]*)\]")
+_OPERAND_RE = re.compile(r"%?([\w.\-]+)")
 
 
 def _dims(dim_str: str) -> List[int]:
@@ -56,8 +60,22 @@ def parse_module(hlo_text: str) -> Dict[str, List[str]]:
     return comps
 
 
-def _dot_flops(line: str) -> float:
-    """2 x |out| x contracted extent, all read off the instruction text."""
+def _shape_table(comps) -> Dict[str, List[int]]:
+    """{instruction name: dims} over every computation (names are unique)."""
+    table: Dict[str, List[int]] = {}
+    for name, lines in comps.items():
+        if name == "__entry__":
+            continue
+        for line in lines:
+            m = _DEF_RE.match(line)
+            if m:
+                table[m.group(1)] = _dims(m.group(2))
+    return table
+
+
+def _dot_flops(line: str, shapes: Dict[str, List[int]]) -> float:
+    """2 x |out| x contracted extent; the lhs shape is read inline or looked
+    up by the operand's name in ``shapes``."""
     lhs, _, rhs = line.partition("= ")
     out_shapes = _SHAPE_RE.findall(rhs.split("(", 1)[0])
     if not out_shapes:
@@ -65,12 +83,19 @@ def _dot_flops(line: str) -> float:
     out_elems = 1
     for d in _dims(out_shapes[0][1]):
         out_elems *= d
-    # First operand's shape: inside the parens, first typed operand.
-    operands = _SHAPE_RE.findall(rhs.split("(", 1)[1])
+    args = rhs.split("(", 1)[1]
+    first = args.split(",", 1)[0]
+    typed = _SHAPE_RE.findall(first)
+    named = _OPERAND_RE.search(first)
+    if typed:
+        lhs_dims = _dims(typed[0][1])
+    elif named and named.group(1) in shapes:
+        lhs_dims = shapes[named.group(1)]
+    else:
+        lhs_dims = None
     m = _DOT_CONTRACT_RE.search(line)
-    if not operands or not m:
+    if lhs_dims is None or not m:
         return 2.0 * out_elems  # degenerate: treat as elementwise-ish
-    lhs_dims = _dims(operands[0][1])
     k = 1
     for idx in _dims(m.group(1)):
         if idx < len(lhs_dims):
@@ -78,9 +103,9 @@ def _dot_flops(line: str) -> float:
     return 2.0 * out_elems * k
 
 
-def _line_flops(line: str) -> float:
+def _line_flops(line: str, shapes: Dict[str, List[int]]) -> float:
     if re.search(r"= .*\bdot\(", line):
-        return _dot_flops(line)
+        return _dot_flops(line, shapes)
     if re.search(r"= .*\bconvolution\(", line):
         # Rare here (whisper stub conv): approximate from output size x window.
         out = _SHAPE_RE.findall(line.split("(", 1)[0])
@@ -109,7 +134,7 @@ def _trip_count(line: str, comps, cond_name) -> int:
     return 1
 
 
-def _comp_flops(name: str, comps, memo) -> float:
+def _comp_flops(name: str, comps, memo, shapes) -> float:
     if name not in comps:
         return 0.0
     if name in memo:
@@ -117,7 +142,7 @@ def _comp_flops(name: str, comps, memo) -> float:
     memo[name] = 0.0  # cycle guard
     total = 0.0
     for line in comps[name]:
-        total += _line_flops(line)
+        total += _line_flops(line, shapes)
         called = _CALLED_RE.findall(line)
         if not called:
             continue
@@ -128,10 +153,10 @@ def _comp_flops(name: str, comps, memo) -> float:
             mc = re.search(r"condition=%?([\w.\-]+)", line)
             cond = mc.group(1) if mc else None
             trips = _trip_count(line, comps, cond)
-            total += trips * _comp_flops(body, comps, memo)
+            total += trips * _comp_flops(body, comps, memo, shapes)
         elif re.search(r"= .*\b(fusion|call|map|conditional|reduce|sort|scatter)\(", line):
             for c in called:
-                total += _comp_flops(c, comps, memo)
+                total += _comp_flops(c, comps, memo, shapes)
     memo[name] = total
     return total
 
@@ -145,7 +170,7 @@ def analyze(hlo_text: str) -> dict:
         return {"flops": 0.0, "dots": 0, "whiles": 0}
     flat = "\n".join("\n".join(v) for k, v in comps.items() if k != "__entry__")
     return {
-        "flops": _comp_flops(entry, comps, {}),
+        "flops": _comp_flops(entry, comps, {}, _shape_table(comps)),
         "dots": len(re.findall(r"= .*\bdot\(", flat)),
         "whiles": len(re.findall(r"= .*\bwhile\(", flat)),
     }

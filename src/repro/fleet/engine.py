@@ -26,11 +26,15 @@ cannot drift apart (the streaming-vs-offline bit-exactness contract).
 offline — the oracle for :meth:`repro.fleet.runtime.FleetRuntime.reroute`'s
 mid-stream routing swaps.
 
-Precision: everything runs under ``jax.experimental.enable_x64`` so prefix
+Precision: everything runs under ``jax.enable_x64`` so prefix
 sums over year-long horizons accumulate in float64 — the batched decision
 sequences ``x`` then match the float64 numpy references
 (:func:`repro.core.togglecci.run_togglecci`) bit-for-bit
 (property-tested in ``tests/test_fleet.py`` / ``tests/test_topology.py``).
+On a TPU, float64 is a pair of float32s (~48 bits), so sums differ from
+numpy's in the low bits; decisions still agree unless a window sum lands
+within ~1e-14 of a threshold (checked at 2048 links x 8760 h by
+``chip_smoke.py``).
 """
 from __future__ import annotations
 
@@ -40,10 +44,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.costmodel import (
     monthly_cumsum,
+    monthly_cumsum_np,
     tiered_marginal_cost_np,
     tiered_marginal_cost_tables,
 )
@@ -111,20 +115,14 @@ def _pair_stage(arrays, demand: jax.Array, *, hours_per_month: int,
     d = jnp.minimum(demand.astype(f), cap[:, None])                   # (P, T)
     month_cum = monthly_cumsum(d, hours_per_month)
     if use_pallas:
-        # f32 kernel path: pad T to a block multiple (zero demand rows
-        # cost zero) and interpret the kernel off-TPU.
-        from repro.kernels.tiered_cost import DEFAULT_BLOCK_T
-
-        T = d.shape[1]
-        pad = (-T) % DEFAULT_BLOCK_T
-        z = lambda a: jnp.pad(a.astype(jnp.float32), ((0, 0), (0, pad)))
+        # f32 kernel path (the kernel pads to whole blocks itself);
+        # interpreted off-TPU.
+        f32 = lambda a: a.astype(jnp.float32)
         vpn_transfer = tiered_cost_batched(
-            z(month_cum),
-            z(d),
-            arrays.tier_bounds.astype(jnp.float32),
-            arrays.tier_rates.astype(jnp.float32),
+            f32(month_cum), f32(d),
+            f32(arrays.tier_bounds), f32(arrays.tier_rates),
             interpret=jax.default_backend() != "tpu",
-        )[:, :T].astype(f)
+        ).astype(f)
     else:
         vpn_transfer = tiered_marginal_cost_tables(
             month_cum, d, arrays.tier_bounds, arrays.tier_rates
@@ -266,7 +264,7 @@ def plan_fleet(
       dict of per-link arrays — see ``_build_plan_fn`` (plus ``demand``, an
       alias of ``pair_demand`` kept for the per-link view).
     """
-    with enable_x64():
+    with jax.enable_x64():
         kind = "reactive"
         if isinstance(fleet, FleetSpec):
             hours_per_month = fleet.hours_per_month
@@ -338,7 +336,7 @@ def plan_topology(
     Returns:
       dict of per-port arrays — see ``_build_plan_fn``.
     """
-    with enable_x64():
+    with jax.enable_x64():
         kind = "reactive"
         if isinstance(topo, TopologySpec):
             hours_per_month = topo.hours_per_month
@@ -395,7 +393,7 @@ def replay_plan_topology(
     assert all(a < b for a, b in zip(starts, starts[1:])), (
         "schedule starts must be strictly increasing"
     )
-    with enable_x64():
+    with jax.enable_x64():
         demand = jnp.asarray(demand, jnp.float64)
         T = demand.shape[1]
         M = arrays.n_ports
@@ -473,15 +471,6 @@ def offline_stream_oracle(
     )
 
 
-def _month_cum_np(d: np.ndarray, hours_per_month: int) -> np.ndarray:
-    """Exclusive within-month prefix volume of one (T,) demand row."""
-    T = d.shape[0]
-    t_idx = np.arange(T)
-    month_start = (t_idx // hours_per_month) * hours_per_month
-    full = np.concatenate([[0.0], np.cumsum(d)])
-    return full[:-1] - full[month_start]
-
-
 def topology_port_costs_reference(
     topo: TopologySpec, demand, routing
 ) -> Dict[str, np.ndarray]:
@@ -502,7 +491,7 @@ def topology_port_costs_reference(
     d = np.minimum(demand, topo.row_capacities()[:, None])
     vpn_pair = np.zeros((P, T))
     for i in range(P):
-        cum = _month_cum_np(d[i], topo.hours_per_month)
+        cum = monthly_cumsum_np(d[i], topo.hours_per_month)
         vpn_pair[i] = topo.row_vpn_lease(i) + tiered_marginal_cost_np(
             topo.row_vpn_tier(i), cum, d[i]
         )

@@ -537,7 +537,6 @@ def forecast_fleet_policy(
     and baked into the policy, so the streaming runtime can gate on them
     without ever seeing the full horizon.
     """
-    from jax.experimental import enable_x64
 
     from .engine import routed_cost_series
 
@@ -549,7 +548,7 @@ def forecast_fleet_policy(
         forecast_horizon_hours(arrays.toggle),
         **train_kw,
     )
-    with enable_x64():
+    with jax.enable_x64():
         s = routed_cost_series(
             arrays,
             jnp.asarray(demand, jnp.float64),
@@ -582,7 +581,6 @@ def forecast_topology_policy(
     engine's port-aggregated series and baked into the policy (streaming-
     runtime ready), exactly as in :func:`forecast_fleet_policy`.
     """
-    from jax.experimental import enable_x64
 
     from .engine import routed_cost_series
 
@@ -610,7 +608,7 @@ def forecast_topology_policy(
         forecast_horizon_hours(arrays.toggle),
         **train_kw,
     )
-    with enable_x64():
+    with jax.enable_x64():
         s = routed_cost_series(
             arrays,
             jnp.asarray(demand, jnp.float64),

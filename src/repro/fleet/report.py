@@ -29,8 +29,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.togglecci import OFF, ON
 
@@ -482,7 +482,7 @@ def build_topology_report(
         common policy-controlled baseline every savings metric compares
         against (the spec's default kind may be one the engine cannot
         resolve on its own, e.g. "forecast")."""
-        with enable_x64():
+        with jax.enable_x64():
             arr = t.stack(rt, jnp.float64)
             pol = reactive_policy(arr.toggle, renew_in_chunks=renew_in_chunks)
         out = plan_topology(

@@ -322,7 +322,7 @@ def padded_operand_np(
     zero weights, primary padded to ``n_rows`` with ``pad_port``.
 
     Returns a :class:`RoutingOperand` of NUMPY fields (the pool tiles and
-    uploads them itself under ``enable_x64``).
+    uploads them itself under ``jax.enable_x64``).
     """
     tight = plan.total_hops
     assert n_legs >= tight, f"legs_cap {n_legs} < {tight} routed legs"

@@ -53,9 +53,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
-from repro.core.costmodel import tiered_marginal_cost_tables
+from repro.core.costmodel import tier_segment
 from repro.core.planner import COMPRESS_RATIO, collective_mode
 from repro.obs.metrics import flatten_ring, init_ring, reset_ring, update_ring
 
@@ -71,16 +70,14 @@ class RuntimeState(NamedTuple):
     """The explicit carry of one streaming step.
 
     Split by residence: the FSM carry and the forecaster's SSM state are
-    device-side (donated through the jitted tick); everything sequential —
-    the float64 cost/demand PREFIX accumulators and the prefix ring buffers
-    — lives host-side in numpy. That split is deliberate twice over: (1)
-    numpy's elementwise float64 adds/moves are exactly the ``np.cumsum``
-    prefixes the offline references use, so streaming stays bit-exact by
-    construction (XLA fuses a+b*c into FMA and turns cumsum into a parallel
-    prefix — neither matches); (2) an in-jit ring buffer defeats XLA's
-    donation aliasing (the read forces a copy-on-write of the whole ring
-    every tick — ~Hbuf x rows x 8 bytes of memcpy that host-side slot
-    assignment does for free).
+    device-side (donated through the jitted step). The float64 cost/demand
+    PREFIX accumulators are ADDED on the device, in the same sequential
+    order as the offline engine's ``prefix_sum``, and mirrored here in
+    numpy; the prefix ring buffers live host-side only (an in-jit ring
+    defeats XLA's donation aliasing: the read forces a copy-on-write of the
+    whole ring every step). Every add happening on the device keeps streams
+    bit-exact with offline plans on a TPU too, whose float64 is a pair of
+    float32s and so differs from numpy's in the low bits.
 
     Demand/billing rows are per PAIR (== per link in fleet mode); cost
     prefix rows are per PORT (== per link in fleet mode).
@@ -100,12 +97,12 @@ class RuntimeState(NamedTuple):
                             # modes() consume; swappable mid-stream via
                             # FleetRuntime.reroute() at a fixed leg bound
     dcum: np.ndarray        # (P,) cumulative clipped billed demand, == full[t]
-    dcum_month: np.ndarray  # (P,) dcum at the current month's start
+    month_vol: np.ndarray   # (P,) clipped billed demand so far this month
     vpn_pref: np.ndarray    # (M,) exclusive prefix of hourly VPN cost
     cci_pref: np.ndarray    # (M,) exclusive prefix of hourly CCI cost
     ring_vpn: np.ndarray    # (Hbuf, M) past vpn_pref values, slot = hour % Hbuf
-                            # — hour-MAJOR so per-tick writes and chunked
-                            # multi-row commits are contiguous memcpys
+                            # — hour-MAJOR so chunk commits are
+                            # contiguous memcpys
     ring_cci: np.ndarray    # (Hbuf, M)
     pred_live: np.ndarray   # (M,) next-tick demand forecast (zeros when unused)
     metrics: object         # device: obs MetricsRing pytree (None when the
@@ -201,141 +198,6 @@ class RuntimeConfig:
         return self
 
 
-def _build_step(
-    topology: bool, pred_source: Optional[str], endo: bool,
-    obs: bool = False, drain: bool = False,
-):
-    """This tick's jitted compute: pricing + forecast gates + FSM transition.
-
-    The sequential accumulators (prefixes, rings, tier state) stay host-side
-    (see :class:`RuntimeState`); their per-tick reductions enter PACKED into
-    one ``(k · rows,)`` float64 operand, and everything the host needs back
-    leaves as one packed float64 result — host↔device transfers cost ~100µs
-    EACH on CPU, so one each way per tick is the difference between 1e5 and
-    1e6+ link-steps/s. The tick counter rides the device carry for the same
-    reason.
-
-    ``pred_source``: ``None`` (memoryless policies), ``"replay"`` (index the
-    policy's precomputed ``pred_demand`` column — the bit-exactness path) or
-    ``"live"`` (carried SSM state, endogenous-demand capable). ``endo``:
-    the packed input carries a separate CCI-path demand vector (endogenous
-    two-shape pricing).
-
-    ``obs``: update the carried :class:`repro.obs.metrics.MetricsRing` from
-    this tick's outputs (pure consumers — decisions stay bit-identical with
-    observability on or off). ``drain``: additionally append the flattened
-    ring to the packed result (the drain rides the SAME single D2H transfer)
-    and return a zeroed ring. Both are STATIC — two compiled tick variants
-    per configuration, chosen per tick by the host at the drain cadence, so
-    the hot path stays one dispatch with no per-tick recompiles.
-    """
-
-    def step(arrays, policy, fc, fsm, ssm_h, t, routing, ring, hist_edges, packed):
-        f = jnp.result_type(float)
-        P = (arrays.pair_capacity if topology else arrays.capacity).shape[0]
-        M = arrays.toggle.theta1.shape[0]
-
-        # --- unpack the host's per-tick vector ----------------------------
-        parts = [P] + ([P] if endo else []) + [P, M, M] + ([M] if pred_source == "live" else [])
-        offs = np.concatenate([[0], np.cumsum(parts)])
-        chunk = iter(
-            jax.lax.slice(packed, (int(a),), (int(b),))
-            for a, b in zip(offs[:-1], offs[1:])
-        )
-        demand_t = next(chunk)
-        cci_demand_t = next(chunk) if endo else None
-        month_cum = next(chunk)
-        r_vpn = next(chunk)
-        r_cci = next(chunk)
-        pred_live = next(chunk) if pred_source == "live" else None
-
-        # --- pricing stage: this tick's column of *_cost_series -----------
-        if topology:
-            d_pair = jnp.minimum(demand_t.astype(f), arrays.pair_capacity)
-            vpn_transfer = tiered_marginal_cost_tables(
-                month_cum[:, None], d_pair[:, None],
-                arrays.tier_bounds, arrays.tier_rates,
-            )[:, 0]
-            vpn_pair = arrays.L_vpn + vpn_transfer                    # (P,)
-            # Aggregate through the RuntimeState's swappable routing
-            # operand: the padded LEG list (each leg one row→port
-            # attachment with a VPN share and an attachment weight),
-            # segment-summed over leg_port in leg order — the same
-            # formulation as the offline _route_stage (bit-exactness: a
-            # 1-hop plan's legs are the identity gather with unit weights,
-            # and padding legs add exact +0.0) and O(E) per tick instead
-            # of an O(M·P) dense matvec.
-            lp, lm = routing.leg_pair, routing.leg_port
-            vw, aw = routing.vpn_w, routing.attach_w
-            seg = lambda v: jax.ops.segment_sum(v, lm, num_segments=M)
-            vpn_t = seg(vpn_pair[lp] * vw)                            # (M,)
-            d_cci = (
-                d_pair if cci_demand_t is None
-                else jnp.minimum(cci_demand_t.astype(f), arrays.pair_capacity)
-            )
-            d_bill = jnp.minimum(seg(d_cci[lp] * aw), arrays.port_capacity)
-            n_pairs = seg(aw)
-            cci_t = (
-                arrays.L_cci + arrays.V_cci * n_pairs + arrays.c_cci * d_bill
-            )
-            d_row = jnp.minimum(seg(d_pair[lp] * aw), arrays.port_capacity)
-        else:
-            d_pair = jnp.minimum(demand_t.astype(f), arrays.capacity)  # (N,)
-            vpn_transfer = tiered_marginal_cost_tables(
-                month_cum[:, None], d_pair[:, None],
-                arrays.tier_bounds, arrays.tier_rates,
-            )[:, 0]
-            vpn_t = arrays.L_vpn + vpn_transfer
-            d_cci = (
-                d_pair if cci_demand_t is None
-                else jnp.minimum(cci_demand_t.astype(f), arrays.capacity)
-            )
-            cci_t = (arrays.L_cci + arrays.V_cci) + arrays.c_cci * d_cci
-            d_row = d_pair
-
-        # --- policy extras (forecast gates) -------------------------------
-        if pred_source is None:
-            extras = None
-        else:
-            if pred_source == "replay":
-                pred_t = jax.lax.dynamic_index_in_dim(
-                    policy.pred_demand, t, axis=1, keepdims=False
-                )
-            else:
-                pred_t = pred_live
-            extras = predicted_mode_costs(pred_t, policy.cost_coef, f)
-
-        # --- one FSM transition per row (the shared policy layer) ---------
-        fsm, (x_t, state_t) = jax.vmap(
-            lambda p, c, w, e: p.step(c, w, e)
-        )(policy, fsm, (r_vpn, r_cci), extras)
-
-        outs = [x_t.astype(f), state_t.astype(f), vpn_t, cci_t, d_pair]
-        if pred_source == "live":
-            from repro.models.ssm import demand_forecaster_step
-
-            u_t = jnp.log1p((d_row / fc["scale"]).astype(jnp.float32))
-            ssm_h, y_t = demand_forecaster_step(fc["params"], ssm_h, u_t)
-            outs.append(
-                jnp.maximum(jnp.expm1(y_t.astype(f)), 0.0) * fc["scale"]
-            )
-        if obs:
-            ring = update_ring(
-                ring, hist_edges,
-                x_t=x_t, state_t=state_t, vpn_t=vpn_t, cci_t=cci_t,
-                d_pair=d_pair, d_row=d_row, month_cum=month_cum,
-                tier_bounds=arrays.tier_bounds,
-                routing_idx=routing.primary if topology else None,
-                pred_t=pred_t if pred_source is not None else None,
-            )
-            if drain:
-                outs.append(flatten_ring(ring))
-                ring = reset_ring(ring)
-        return fsm, ssm_h, t + 1, ring, jnp.concatenate(outs)
-
-    return step
-
-
 def _build_step_many(
     topology: bool, pred_source: Optional[str], endo: bool,
     obs: bool = False, drain: bool = False, K: int = 1,
@@ -351,9 +213,9 @@ def _build_step_many(
     op is elementwise per (row, hour), so batching reassociates nothing).
     What remains sequential is genuinely sequential state:
 
-    * the billing calendar (``dcum``/``dcum_month`` month-boundary
-      resets) — a tiny ``lax.scan`` over (P,) adds, bit-identical to the
-      host's numpy replay because each hour is one lone f64 add/select;
+    * the billing calendar (``dcum`` and the month-to-date volume, reset
+      at month boundaries) — a tiny ``lax.scan`` over (P,) adds, the same
+      adds in the same order as the offline ``monthly_cumsum``;
     * the toggle window prefixes — same tiny scan shape, emitting the
       start-of-hour snapshots the window sums and ring writes need;
     * the FSM transition itself (+ the SSM forecaster step and metrics
@@ -382,10 +244,10 @@ def _build_step_many(
     variant firing on the chunk's last hour, which is the only hour a
     drain cadence boundary is allowed to touch (the caller asserts the
     alignment). Per-hour outputs come home as ``(K, rows)`` planes in the
-    per-tick ``po`` order with the window sums appended, so the host can
-    reconstruct each hour's ``step()`` dict and replay the commits
-    through its numpy accumulators. Bit-exactness vs per-tick ``step()``
-    is property-tested in ``tests/test_fleet_runtime.py``.
+    per-tick ``po`` order with the window sums and prefix snapshots
+    appended, so the host can build each hour's ``step()`` dict and mirror
+    the accumulators. Chunkings are property-tested bit-exact against each
+    other in ``tests/test_fleet_runtime.py``.
     """
 
     def step_many(arrays, policy, fc, fsm, ssm_h, t, routing, ring,
@@ -393,7 +255,7 @@ def _build_step_many(
         f = jnp.result_type(float)
         P = (arrays.pair_capacity if topology else arrays.capacity).shape[0]
         M = arrays.toggle.theta1.shape[0]
-        dcum, dcum_month, vpn_pref, cci_pref, pred_live = seq
+        dcum, month_vol, vpn_pref, cci_pref, pred_live = seq
         h = jnp.broadcast_to(jnp.asarray(arrays.toggle.h, jnp.int32), (M,))
         t0 = t
         ks = jnp.arange(K, dtype=jnp.result_type(t))
@@ -425,34 +287,31 @@ def _build_step_many(
             d_cci_raw = d_pair
 
         # Billing calendar: sequential month-boundary resets over (P,)
-        # vectors (one f64 add + one select per hour — bit-identical to the
-        # host replay; a parallel cumsum would reassociate, this does not).
+        # vectors (one f64 add + one select per hour, as prefix_sum adds; a
+        # parallel cumsum would reassociate, this does not).
         def cal_body(carry, d_k):
-            dcum, dcum_month, tk = carry
-            dcum_month = jnp.where(tk % hpm == 0, dcum, dcum_month)
-            return (dcum + d_k, dcum_month, tk + 1), dcum - dcum_month
+            dcum, mv, tk = carry
+            mv = jnp.where(tk % hpm == 0, jnp.zeros_like(mv), mv)
+            return (dcum + d_k, mv + d_k, tk + 1), mv
 
-        (dcum, dcum_month, _), month_cum = jax.lax.scan(
-            cal_body, (dcum, dcum_month, t0), d_pair
+        (dcum, month_vol, _), month_cum = jax.lax.scan(
+            cal_body, (dcum, month_vol, t0), d_pair
         )                                                     # (K, P)
 
         # Tier pricing, unrolled over the Kt tier columns so every
         # intermediate is a fusible (K, P) plane. This is the same
         # per-element f64 op chain as tiered_marginal_cost_tables —
-        # min/max/clip per segment and a left fold from zero over tiers —
+        # tier_segment per tier and a left fold from zero over tiers —
         # so the bits match the per-tick path exactly; the broadcast
         # (K, P, Kt) temps of the table formulation stay unfused on
         # XLA:CPU and cost ~15MB of memory traffic per chunk.
         bounds = arrays.tier_bounds.astype(f)                 # (P, Kt)
         rates = arrays.tier_rates.astype(f)
-        hi = month_cum + d_pair
         vpn_transfer = jnp.zeros((), f)
         prev_b = jnp.zeros((bounds.shape[0],), f)
         for j in range(bounds.shape[-1]):
-            seg_j = jnp.clip(
-                jnp.minimum(hi, bounds[None, :, j])
-                - jnp.maximum(month_cum, prev_b[None, :]),
-                0.0,
+            seg_j = tier_segment(
+                month_cum, d_pair, prev_b[None, :], bounds[None, :, j]
             )
             # Same FMA guard as tiered_marginal_cost_tables: the where()
             # keeps LLVM from contracting the product into the fold add
@@ -581,7 +440,7 @@ def _build_step_many(
         # --- commit + assemble --------------------------------------------
         # Ring writes are the HOST's job (its replay loop updates the numpy
         # ring twins); the device carry is the small vectors only.
-        seq_out = (dcum, dcum_month, vpn_pref, cci_pref, pred_live)
+        seq_out = (dcum, month_vol, vpn_pref, cci_pref, pred_live)
         # Per-hour outputs ship home as separate (K, rows) planes riding
         # the one result tuple, in the per-tick po order with the window
         # sums appended. Concatenating them into a single (K, W) block
@@ -634,7 +493,7 @@ def resolve_runtime_operands(spec, config: RuntimeConfig) -> ResolvedRuntime:
     :class:`ResolvedRuntime`). Pure construction — no carried state is
     allocated here."""
     config = config.validate()
-    with enable_x64():
+    with jax.enable_x64():
         kind = "reactive"
         hours_per_month = int(config.hours_per_month)
         resolved_spec = None
@@ -765,7 +624,7 @@ class FleetRuntime:
             obs=obs,
         ).validate()
         ops = resolve_runtime_operands(spec, self.config)
-        with enable_x64():
+        with jax.enable_x64():
             self._spec = ops.spec
             self.topology = ops.topology
             self.arrays = ops.arrays
@@ -841,22 +700,6 @@ class FleetRuntime:
         self._routing_idx_np = plan.primary
         self._routing_idx = jnp.asarray(self._routing_idx_np, jnp.int32)
 
-    def _step_fn(self, endo: bool, drain: bool = False):
-        key = (self.topology, self.pred_source, endo, self.obs is not None, drain)
-        fn = _STEP_CACHE.get(key)
-        if fn is None:
-            # Donate the metrics ring (arg 7): the caller always replaces it
-            # with the returned ring, and in-place buffer reuse is what makes
-            # the per-tick gauge column write ~free (a non-donated
-            # dynamic-update-slice copies the whole ring every tick).
-            fn = _STEP_CACHE.setdefault(key, jax.jit(
-                _build_step(*key),
-                donate_argnums=(7,) if self.obs is not None else (),
-            ))
-            if self.obs is not None:
-                self.obs.note_compile()
-        return fn
-
     def _step_many_fn(self, endo: bool, drain: bool, K: int):
         key = (
             "many", self.topology, self.pred_source, endo,
@@ -883,20 +726,19 @@ class FleetRuntime:
         across chunks. The (M, Hbuf) window RINGS deliberately stay host-only
         — the chunked step reads them through a host gather packed into the
         H2D block (see :func:`_build_step_many`), so the device never pays
-        ring-sized memory traffic. Invalidated whenever the host copy
-        advances without the device (per-tick ``step()``, ``reset()``)."""
+        ring-sized memory traffic. Invalidated by ``reset()``."""
         if self._dev_seq is None:
             st = self._state
-            with enable_x64():
+            with jax.enable_x64():
                 self._dev_seq = jax.device_put((
-                    st.dcum, st.dcum_month, st.vpn_pref, st.cci_pref,
+                    st.dcum, st.month_vol, st.vpn_pref, st.cci_pref,
                     st.pred_live,
                 ))
         return self._dev_seq
 
     def reset(self) -> None:
         """Rewind to tick 0 (fresh carry; operands and policy unchanged)."""
-        with enable_x64():
+        with jax.enable_x64():
             fsm = jax.vmap(lambda p: p.init_carry())(self.policy)
             t_dev = jnp.int32(0)
         M, P = self.n_rows, self.n_demand_rows
@@ -909,7 +751,7 @@ class FleetRuntime:
             pred_live = z(M)
         metrics = None
         if self.obs is not None:
-            with enable_x64():  # f64 ring fields silently downcast outside
+            with jax.enable_x64():  # f64 ring fields silently downcast outside
                 metrics = init_ring(
                     M, self.obs.cadence,
                     self.obs.config.hist_bins, self.obs.n_tiers,
@@ -922,7 +764,7 @@ class FleetRuntime:
             t_dev=t_dev,
             routing=self.arrays.routing if self.topology else None,
             dcum=z(P),
-            dcum_month=z(P),
+            month_vol=z(P),
             vpn_pref=z(M),
             cci_pref=z(M),
             ring_vpn=z(self.hbuf, M),
@@ -943,83 +785,16 @@ class FleetRuntime:
         prices the CCI counterfactual on its own volume (endogenous demand —
         the two paths carry differently-compressed traffic). Returns this
         hour's per-row decision/cost arrays; the FSM state that SERVES the
-        hour is ``out["state"]`` (map it with :func:`modes`)."""
-        t0 = time.perf_counter() if self.obs is not None else 0.0
-        self._dev_seq = None  # host accumulators advance without the device
-        st = self._state
-        t = st.t
-        M, P = self.n_rows, self.n_demand_rows
-        # Host-side sequential reductions (see RuntimeState: numpy float64
-        # keeps these bit-identical to the offline np.cumsum prefixes).
-        if t % self.hours_per_month == 0:
-            st.dcum_month[:] = st.dcum
-        month_cum = st.dcum - st.dcum_month
-        lo = np.maximum(0, t - self._h_np)
-        r_vpn = st.vpn_pref - st.ring_vpn[lo % self.hbuf, self._rows_idx]
-        r_cci = st.cci_pref - st.ring_cci[lo % self.hbuf, self._rows_idx]
+        hour is ``out["state"]`` (map it with :func:`modes`).
 
-        d = np.asarray(demand_t, np.float64)
-        assert d.shape == (P,), (d.shape, P)
-        endo = cci_demand_t is not None
-        parts = [d]
-        if endo:
-            parts.append(np.asarray(cci_demand_t, np.float64))
-        parts += [month_cum, r_vpn, r_cci]
-        if self.pred_source == "live":
-            parts.append(st.pred_live)
-        drain = (
-            self.obs is not None and (t + 1) % self.obs.cadence == 0
+        This is :meth:`step_many` on a one-hour block: there is one stepping
+        path, so per-tick and chunked streams are the same computation."""
+        col = lambda v: np.asarray(v, np.float64)[:, None]
+        out = self.step_many(
+            col(demand_t),
+            cci_demand_block=None if cci_demand_t is None else col(cci_demand_t),
         )
-        packed_in = np.concatenate(parts)
-        with enable_x64():
-            fsm, ssm_h, t_dev, ring, packed_out = self._step_fn(endo, drain)(
-                self.arrays, self.policy, self._fc, st.fsm, st.ssm_h,
-                st.t_dev, st.routing, st.metrics, self._obs_edges,
-                jax.device_put(packed_in),
-            )
-        po = np.asarray(packed_out)
-        x = po[0:M].astype(np.int64)
-        state = po[M:2 * M].astype(np.int64)
-        vpn_t = po[2 * M:3 * M]
-        cci_t = po[3 * M:4 * M]
-        d_pair = po[4 * M:4 * M + P]
-        base = 4 * M + P
-
-        # Commit this tick: ring slots take pref[t] BEFORE the prefixes
-        # absorb this hour's costs (the exclusive-prefix convention).
-        slot = t % self.hbuf
-        st.ring_vpn[slot] = st.vpn_pref
-        st.ring_cci[slot] = st.cci_pref
-        np.add(st.vpn_pref, vpn_t, out=st.vpn_pref)
-        np.add(st.cci_pref, cci_t, out=st.cci_pref)
-        np.add(st.dcum, d_pair, out=st.dcum)
-        if self.pred_source == "live":
-            pred_live = po[base:base + M]
-            base += M
-        else:
-            pred_live = st.pred_live
-        self._state = st._replace(
-            t=t + 1, fsm=fsm, ssm_h=ssm_h, t_dev=t_dev,
-            pred_live=pred_live, metrics=ring,
-        )
-        out = {
-            "x": x,                        # (rows,) 0/1 — CCI serving this hour
-            "state": state,                # (rows,) FSM state codes
-            "r_vpn": r_vpn,
-            "r_cci": r_cci,
-            "vpn_cost": vpn_t,             # this hour's counterfactual costs
-            "cci_cost": cci_t,
-            "cost": np.where(x == 1, cci_t, vpn_t),
-        }
-        if self.obs is not None:
-            self.obs.record_step(
-                t, out, d_pair=d_pair, demand_t=d, endo=endo,
-                h2d_bytes=packed_in.nbytes, d2h_bytes=po.nbytes,
-                dt_s=time.perf_counter() - t0,
-            )
-            if drain:
-                self.obs.record_drain(t + 1, po[base:])
-        return out
+        return {k: v[:, 0] for k, v in out.items()}
 
     def step_many(
         self, demand_block, *, cci_demand_block=None
@@ -1033,13 +808,11 @@ class FleetRuntime:
         dict with ``(rows, K)`` stacked arrays (the :meth:`run` layout).
 
         Contract: ``step_many`` over any chunking of a demand stream is
-        BIT-EXACT vs per-tick :meth:`step` — decisions, window sums, and
-        the host float64 billing prefixes (``step_many(K=1)`` ≡ ``step()``
-        exactly). Inside a chunk the carry runs on device in the same
-        sequential order (see :func:`_build_step_many`); at chunk
-        boundaries the host accumulators are re-synchronized by replaying
-        the K returned cost columns through the same numpy adds, so
-        per-tick and chunked stepping interleave freely and
+        BIT-EXACT vs per-tick :meth:`step` (which is ``step_many`` on one
+        hour) — decisions, window sums, and the float64 prefixes. The
+        carry runs on device in one sequential order whatever the chunking
+        (see :func:`_build_step_many`) and the host mirrors it at chunk
+        boundaries, so per-tick and chunked stepping interleave freely and
         :meth:`reroute` at a chunk boundary behaves exactly as it does
         between two ``step()`` calls. With observability on, the drain
         cadence must not fall strictly inside a chunk (pick K dividing the
@@ -1110,7 +883,7 @@ class FleetRuntime:
             )
             drain = boundary == t + K
         fn = self._step_many_fn(endo, drain, K)
-        with enable_x64():
+        with jax.enable_x64():
             fsm, ssm_h, t_dev, ring, seq, planes, drain_vec = fn(
                 self.arrays, self.policy, self._fc, st.fsm, st.ssm_h,
                 st.t_dev, st.routing, st.metrics, self._obs_edges,
@@ -1130,21 +903,19 @@ class FleetRuntime:
         snap_v = np.asarray(next(it))
         snap_c = np.asarray(next(it))
 
-        # Re-synchronize the host accumulators from the device's sequential
-        # scans — bit-identical f64 twins of the per-tick numpy adds (the
-        # calendar and prefix scans perform the same adds in the same
-        # order), so adopting them IS the replay. ``snap[k]`` is the prefix
-        # BEFORE hour t+k (the ring-snapshot / exclusive-prefix
-        # convention); the seq carry holds the post-chunk accumulators.
+        # Mirror the device's sequential scans into the host accumulators.
+        # ``snap[k]`` is the prefix BEFORE hour t+k (the ring-snapshot /
+        # exclusive-prefix convention); the seq carry holds the post-chunk
+        # accumulators.
         tks = t + np.arange(K)
         w = min(K, self.hbuf)  # K > hbuf: earlier slots would be rewritten
         st.ring_vpn[tks[K - w:] % self.hbuf] = snap_v[K - w:K]
         st.ring_cci[tks[K - w:] % self.hbuf] = snap_c[K - w:K]
-        dcum_d, dcum_month_d, vpn_pref_d, cci_pref_d, _ = seq
+        dcum_d, month_vol_d, vpn_pref_d, cci_pref_d, _ = seq
         st.vpn_pref[:] = np.asarray(vpn_pref_d)
         st.cci_pref[:] = np.asarray(cci_pref_d)
         st.dcum[:] = np.asarray(dcum_d)
-        st.dcum_month[:] = np.asarray(dcum_month_d)
+        st.month_vol[:] = np.asarray(month_vol_d)
         self._state = st._replace(
             t=t + K, fsm=fsm, ssm_h=ssm_h, t_dev=t_dev,
             pred_live=(
@@ -1219,7 +990,7 @@ class FleetRuntime:
         )
         old_idx = self._routing_idx_np.copy()
         M, P = self.n_rows, self.n_demand_rows
-        with enable_x64():
+        with jax.enable_x64():
             plan = as_routing_plan(
                 routing, n_ports=M, context="FleetRuntime.reroute"
             )
@@ -1257,7 +1028,7 @@ class FleetRuntime:
         ring = self._state.metrics
         if int(ring.small[0]) == 0:
             return
-        with enable_x64():
+        with jax.enable_x64():
             vec = np.asarray(flatten_ring(ring))
             self._state = self._state._replace(metrics=reset_ring(ring))
         self.obs.record_drain(self.t, vec)
@@ -1482,7 +1253,7 @@ def streaming_forecast_policy(
 
     history = np.asarray(history, np.float64)
     window = forecast_horizon_hours(arrays.toggle)
-    with enable_x64():
+    with jax.enable_x64():
         hist = jnp.asarray(history, jnp.float64)
         s = routed_cost_series(arrays, hist, hours_per_month=hours_per_month)
         coef = fit_cost_coef(s.row_demand, s.vpn, s.cci)

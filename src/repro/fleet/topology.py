@@ -680,12 +680,9 @@ def refine_routing(
     saving always at index 3 — and ``move_mix`` counting applied moves per
     kind (``single`` / ``swap`` / ``relay``).
     """
-    from jax.experimental import enable_x64
 
-    from repro.core.costmodel import tiered_marginal_cost_np
+    from repro.core.costmodel import monthly_cumsum_np, tiered_marginal_cost_np
 
-    # Engine sits above this module — import its reference helper lazily.
-    from .engine import _month_cum_np
     from .policy import policy_scan, reactive_policy
 
     plan = as_routing_plan(
@@ -705,7 +702,7 @@ def refine_routing(
     # inputs; group rows already carry the n_leaves scaling).
     vpn_pair = np.zeros((P, T))
     for i in range(P):
-        cum = _month_cum_np(d[i], hpm)
+        cum = monthly_cumsum_np(d[i], hpm)
         vpn_pair[i] = topo.row_vpn_lease(i) + tiered_marginal_cost_np(
             topo.row_vpn_tier(i), cum, d[i]
         )
@@ -741,7 +738,7 @@ def refine_routing(
             T_cci=jnp.asarray([p.T_cci for p in ps], jnp.int32),
         )
 
-    with enable_x64():
+    with jax.enable_x64():
         eval_batch = jax.jit(
             lambda tg, v, c: jax.vmap(
                 lambda p, vv, cc: policy_scan(p, vv, cc)["total_cost"]

@@ -4,9 +4,9 @@ One :class:`FleetGateway` serves many independent tenants — each with its
 own :class:`~repro.fleet.topology.TopologySpec`/routing (or fleet spec),
 policy pytree, billing calendar, horizon, and demand stream — from shared
 capacity-bucketed state pools. Per gateway hour, each non-empty bucket
-costs exactly ONE jitted dispatch: the standalone tick of
-:func:`repro.fleet.runtime._build_step`, ``jax.vmap``-ed over the pool's
-leading slot axis and masked by an alive bitmap. Membership churn (join,
+costs exactly ONE jitted dispatch: the standalone step of
+:func:`repro.fleet.runtime._build_step_many`, ``jax.vmap``-ed over the
+pool's leading slot axis and masked by an alive bitmap. Membership churn (join,
 leave, grow/shrink across buckets, re-route) is pure operand traffic —
 ``.at[slot].set`` writes into fixed-shape pools — so a bucket shape
 compiles once, ever.
@@ -18,8 +18,9 @@ standalone :class:`~repro.fleet.runtime.FleetRuntime` fed the same demand
 ``reroute()`` and departures). That holds because (a) tenant operands
 resolve through the same :func:`~repro.fleet.runtime.resolve_runtime_operands`
 path, (b) padding is provably inert (:mod:`repro.gateway.pool`), and
-(c) the sequential host reductions (prefix rings, month boundaries, tier
-state) are the standalone ones, vectorized over slots in the same float64.
+(c) the sequential reductions (prefixes, month boundaries, tier state)
+run on the device in the standalone order, with the prefix rings mirrored
+host-side exactly as the standalone runtime mirrors them.
 
 Billing stays host-side per tenant (float64 accumulators, surviving
 bucket moves via a carry), metrics ride the PR-6 device ring with a tenant
@@ -40,13 +41,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.planner import collective_mode
 from repro.fleet.routing import as_routing_plan, padded_operand_np
 from repro.fleet.runtime import (
     RuntimeConfig,
-    _build_step,
     _build_step_many,
     resolve_runtime_operands,
 )
@@ -158,7 +157,7 @@ class _Bucket:
         tile = lambda x: jnp.tile(
             x, (n_slots,) + (1,) * getattr(x, "ndim", 0)
         )
-        with enable_x64():
+        with jax.enable_x64():
             # Seed every slot from the first joiner's padded operands —
             # placeholder values for not-yet-allocated slots (their outputs
             # are alive-masked and their FSMs start OFF on zero demand).
@@ -190,7 +189,7 @@ class _Bucket:
         self.m = np.zeros(n_slots, np.int64)      # real decision rows
         self.p = np.zeros(n_slots, np.int64)      # real demand rows
         self.h_np = np.ones((n_slots, m), np.int64)
-        self.dcum, self.dcum_month = z(p), z(p)
+        self.dcum, self.month_vol = z(p), z(p)
         self.vpn_pref, self.cci_pref = z(m), z(m)
         self.ring_vpn, self.ring_cci = z(hb, m), z(hb, m)  # hour-major
         self.bill_real, self.bill_vpn, self.bill_cci = z(m), z(m), z(m)
@@ -199,10 +198,8 @@ class _Bucket:
         self.routing_idx_np = np.zeros((n_slots, p), np.int64)
         self.slots: List[Optional[str]] = [None] * n_slots
         self.free: List[int] = list(range(n_slots))[::-1]
-        # Device-resident twin of the host float64 sequential block, used by
-        # the chunked mega-tick (tick_many) and kept across chunks;
-        # invalidated whenever the host copy moves without the device
-        # (slot writes, per-tick ticks).
+        # Device-resident float64 sequential block, kept across ticks and
+        # mirrored host-side; re-seeded from the host copy after slot writes.
         self._dev_seq = None
 
     @property
@@ -214,11 +211,11 @@ class _Bucket:
         # mega-tick reads them through a host gather packed into the H2D
         # block (see repro.fleet.runtime._build_step_many).
         if self._dev_seq is None:
-            with enable_x64():
+            with jax.enable_x64():
                 self._dev_seq = (
                     jnp.asarray(self.hpm, jnp.int32),
                     jax.device_put((
-                        self.dcum, self.dcum_month, self.vpn_pref,
+                        self.dcum, self.month_vol, self.vpn_pref,
                         self.cci_pref,
                         np.zeros(self.vpn_pref.shape, np.float64),  # pred_live
                     )),
@@ -232,7 +229,7 @@ class _Bucket:
 
     def write_slot(self, s: int, name: str, packed, demand, horizon) -> None:
         """Allocate slot ``s``: pure per-slot operand writes, fixed shapes."""
-        with enable_x64():
+        with jax.enable_x64():
             self.arrays = set_slot(self.arrays, s, packed.arrays)
             self.policy = set_slot(self.policy, s, packed.policy)
             fsm_one = jax.vmap(lambda q: q.init_carry())(packed.policy)
@@ -253,7 +250,7 @@ class _Bucket:
         self.horizon[s] = horizon
         self.m[s], self.p[s] = packed.n_rows, packed.n_pairs
         self.h_np[s] = packed.h_np
-        for a in (self.dcum, self.dcum_month, self.vpn_pref, self.cci_pref,
+        for a in (self.dcum, self.month_vol, self.vpn_pref, self.cci_pref,
                   self.ring_vpn, self.ring_cci, self.bill_real,
                   self.bill_vpn, self.bill_cci, self.gb):
             a[s] = 0.0
@@ -267,7 +264,7 @@ class _Bucket:
         self._dev_seq = None
 
     def clear_slot(self, s: int) -> None:
-        with enable_x64():
+        with jax.enable_x64():
             self.alive_dev = self.alive_dev.at[s].set(0.0)
         self.alive[s] = False
         self.demand[s] = 0.0
@@ -288,7 +285,7 @@ class FleetGateway:
         self.cadence = int(config.cadence)
         self.hist_bins = int(config.hist_bins)
         self._obs = bool(config.obs)
-        with enable_x64():
+        with jax.enable_x64():
             self._edges = (
                 jnp.asarray(default_hist_edges(self.hist_bins), jnp.float64)
                 if self._obs else None
@@ -399,123 +396,17 @@ class FleetGateway:
 
     # --- the mega-tick -----------------------------------------------------
 
-    def _mega_fn(self, key: BucketKey, n_slots: int, drain: bool):
-        ck = key.compile_key(n_slots=n_slots, obs=self._obs, drain=drain)
-        fn = self._compiled.get(ck)
-        if fn is None:
-            step = _build_step(
-                key.topology, key.pred_source, False, self._obs, drain
-            )
-            edges = self._edges
-
-            def mega(arrays, policy, fsm, ssm_h, t, routing, ring,
-                     alive, packed):
-                def one(a, q, f, s, tt, ri, rg, pk):
-                    return step(a, q, None, f, s, tt, ri, rg, edges, pk)
-
-                fsm, ssm_h, t1, ring, out = jax.vmap(one)(
-                    arrays, policy, fsm, ssm_h, t, routing, ring, packed
-                )
-                # Alive-bitmap mask: dead slots emit exact zeros; x1.0 is
-                # bitwise identity for live slots.
-                return fsm, ssm_h, t1, ring, out * alive[:, None]
-
-            fn = jax.jit(
-                mega, donate_argnums=(6,) if self._obs else ()
-            )
-            self._compiled[ck] = fn
-            self.compiles += 1
-        return fn
-
     def tick(self, *, collect: bool = True) -> Dict[str, Dict[str, np.ndarray]]:
-        """Advance EVERY active tenant one hour — one jitted dispatch per
-        non-empty bucket. Returns per-tenant step outputs (the standalone
-        ``FleetRuntime.step`` dict, sliced to real rows) when ``collect``;
-        pass ``collect=False`` on the hot path to skip building them."""
-        hour = self.hours
-        drain = self._obs and (hour + 1) % self.cadence == 0
-        outs: Dict[str, Dict[str, np.ndarray]] = {}
-        finished: List[str] = []
-        for key, buckets in self._buckets.items():
-            for b in buckets:
-                if b.occupied == 0:
-                    continue
-                self._tick_bucket(key, b, drain, collect, outs, finished)
-        self.hours = hour + 1
-        for name in finished:
-            self._finish(name, "done")
-        self._drain_admission_queue()
-        return outs
-
-    def _tick_bucket(self, key, b, drain, collect, outs, finished) -> None:
-        M, P = key.rows_cap, key.pairs_cap
-        # Vectorized standalone host math (numpy float64, one row per slot —
-        # elementwise identical to FleetRuntime.step's sequential block).
-        boundary = b.alive & (b.t % b.hpm == 0)
-        np.copyto(b.dcum_month, b.dcum, where=boundary[:, None])
-        month_cum = b.dcum - b.dcum_month
-        lo = np.maximum(0, b.t[:, None] - b.h_np)
-        idx = (lo % key.hbuf_cap)[:, None, :]
-        r_vpn = b.vpn_pref - np.take_along_axis(b.ring_vpn, idx, axis=1)[:, 0]
-        r_cci = b.cci_pref - np.take_along_axis(b.ring_cci, idx, axis=1)[:, 0]
-        col = np.minimum(b.t, b.demand.shape[2] - 1)
-        d_t = np.take_along_axis(
-            b.demand, col[:, None, None], axis=2
-        )[:, :, 0] * b.alive[:, None]
-        packed_in = np.concatenate([d_t, month_cum, r_vpn, r_cci], axis=1)
-
-        fn = self._mega_fn(key, b.n_slots, drain)
-        with enable_x64():
-            b.fsm, b.ssm_h, b.t_dev, b.ring, po = fn(
-                b.arrays, b.policy, b.fsm, b.ssm_h, b.t_dev,
-                b.routing, b.ring, b.alive_dev,
-                jax.device_put(packed_in),
-            )
-        po = np.asarray(po)
-        x = po[:, 0:M]
-        state = po[:, M:2 * M]
-        vpn_t = po[:, 2 * M:3 * M]
-        cci_t = po[:, 3 * M:4 * M]
-        d_pair = po[:, 4 * M:4 * M + P]
-        base = 4 * M + P
-
-        # Commit: ring slots take pref[t] BEFORE the prefixes absorb this
-        # hour (the exclusive-prefix convention), then billing accumulates
-        # (dead slots are alive-masked upstream, so they add exact zeros).
-        slot_col = (b.t % key.hbuf_cap)[:, None, None]
-        np.put_along_axis(b.ring_vpn, slot_col, b.vpn_pref[:, None, :], axis=1)
-        np.put_along_axis(b.ring_cci, slot_col, b.cci_pref[:, None, :], axis=1)
-        b.vpn_pref += vpn_t
-        b.cci_pref += cci_t
-        b.dcum += d_pair
-        cost = np.where(x == 1.0, cci_t, vpn_t)
-        b.bill_real += cost
-        b.bill_vpn += vpn_t
-        b.bill_cci += cci_t
-        b.gb += d_pair
-
-        vecs = po[:, base:] if drain else None
-        for s, name in enumerate(b.slots):
-            if name is None:
-                continue
-            m, p = int(b.m[s]), int(b.p[s])
-            if collect:
-                xs = x[s, :m].astype(np.int64)
-                outs[name] = {
-                    "x": xs,
-                    "state": state[s, :m].astype(np.int64),
-                    "r_vpn": r_vpn[s, :m],
-                    "r_cci": r_cci[s, :m],
-                    "vpn_cost": vpn_t[s, :m],
-                    "cci_cost": cci_t[s, :m],
-                    "cost": np.where(xs == 1, cci_t[s, :m], vpn_t[s, :m]),
-                }
-            if drain:
-                self._drain_slot(name, b, s, vecs[s].copy(), int(b.t[s]) + 1)
-            if b.t[s] + 1 >= b.horizon[s]:
-                finished.append(name)
-        b.t += 1
-        b._dev_seq = None  # host accumulators advanced without the device
+        """Advance EVERY active tenant one hour: :meth:`tick_many` on one
+        hour (one stepping path, as in the standalone runtime). Returns
+        per-tenant step outputs (the standalone ``FleetRuntime.step`` dict,
+        sliced to real rows) when ``collect``; pass ``collect=False`` on the
+        hot path to skip building them."""
+        outs = self.tick_many(1, collect=collect)
+        return {
+            name: {k: v[:, 0] for k, v in out.items()}
+            for name, out in outs.items()
+        }
 
     # --- the chunked mega-tick (tick_many) ---------------------------------
 
@@ -644,7 +535,7 @@ class FleetGateway:
 
         fn = self._mega_many_fn(key, b.n_slots, drain, K)
         hpm_dev, seq = b.device_seq()
-        with enable_x64():
+        with jax.enable_x64():
             b.fsm, b.ssm_h, b.t_dev, b.ring, seq, ys, dv = fn(
                 b.arrays, b.policy, b.fsm, b.ssm_h, b.t_dev,
                 b.routing, b.ring, b.alive_dev, hpm_dev, seq,
@@ -659,37 +550,25 @@ class FleetGateway:
         r_vpn, r_cci = nxt(), nxt()
         snap_v, snap_c = nxt(), nxt()                    # prefix BEFORE t+k
 
-        # Replay the K commits through the host accumulators.
-        # np.add.accumulate is a strictly sequential left fold, so seeding
-        # it with the carried value reproduces per-tick stepping's add order
-        # TO THE BIT (billing in particular must accumulate hour by hour,
-        # never via a pairwise-summed block): ``acc[:, k]`` is the value
-        # BEFORE hour t+k (the ring snapshot / exclusive-prefix convention),
-        # ``acc[:, K]`` the final carry.
-        seeded = lambda carry, cols: np.add.accumulate(
-            np.concatenate([carry[:, None], cols], axis=1), axis=1
-        )
-        acc_v = seeded(b.vpn_pref, vpn_t)
-        acc_c = seeded(b.cci_pref, cci_t)
-        acc_d = seeded(b.dcum, d_pair)
+        # Mirror the device's sequential carry: the prefix snapshots are
+        # the ring values (snap[k] is the prefix BEFORE hour t+k; dead slots
+        # are alive-masked to zero), the seq carry the post-chunk
+        # accumulators. One summation order — the device's — for every
+        # chunking, as in the standalone runtime.
         tks = b.t[:, None] + np.arange(K)[None, :]       # (n_slots, K)
         w = min(K, key.hbuf_cap)  # K > hbuf: early slots would be rewritten
         wslots = (tks[:, K - w:] % key.hbuf_cap)[:, :, None]
-        # The device prefix snapshots ARE the ring values (snap[k] ==
-        # acc[:, k] bit-for-bit: same sequential f64 adds in the same
-        # order; dead slots are zero both ways).
         np.put_along_axis(b.ring_vpn, wslots, snap_v[:, K - w:K], axis=1)
         np.put_along_axis(b.ring_cci, wslots, snap_c[:, K - w:K], axis=1)
-        b.vpn_pref[...] = acc_v[:, K]
-        b.cci_pref[...] = acc_c[:, K]
-        b.dcum[...] = acc_d[:, K]
-        boundary = tks % b.hpm[:, None] == 0             # (n_slots, K)
-        has = boundary.any(axis=1) & b.alive
-        last = K - 1 - np.argmax(boundary[:, ::-1], axis=1)
-        np.copyto(
-            b.dcum_month,
-            np.take_along_axis(acc_d, last[:, None, None], axis=1)[:, 0],
-            where=has[:, None],
+        for host, dev in zip(
+            (b.dcum, b.month_vol, b.vpn_pref, b.cci_pref), seq[:4]
+        ):
+            host[...] = np.asarray(dev)
+        # Billing accumulates hour by hour on the host: np.add.accumulate
+        # is a strictly sequential left fold, so seeding it with the carry
+        # gives the same bits for any chunking.
+        seeded = lambda carry, cols: np.add.accumulate(
+            np.concatenate([carry[:, None], cols], axis=1), axis=1
         )
         b.bill_real[...] = seeded(
             b.bill_real, np.where(x == 1.0, cci_t, vpn_t)
@@ -755,7 +634,7 @@ class FleetGateway:
         gauges = np.asarray(b.ring.gauges[s], np.float64)
         vec = np.concatenate([small[:5], gauges.reshape(-1), small[5:]])
         self._drain_slot(name, b, s, vec, int(b.t[s]))
-        with enable_x64():
+        with jax.enable_x64():
             b.ring = reset_ring_slot(b.ring, s)
 
     # --- lifecycle ---------------------------------------------------------
@@ -837,7 +716,7 @@ class FleetGateway:
         s = handle.slot
         resolved = self._resolved[name]
         m, p = int(b.m[s]), int(b.p[s])
-        with enable_x64():
+        with jax.enable_x64():
             plan = as_routing_plan(
                 routing, n_ports=m, context="FleetGateway.reroute"
             )

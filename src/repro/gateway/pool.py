@@ -44,7 +44,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.togglecci import ToggleParams
 from repro.fleet.policy import (
@@ -230,11 +229,11 @@ def bucket_key_for(resolved: ResolvedRuntime) -> BucketKey:
 
 def pack_tenant(resolved: ResolvedRuntime, key: Optional[BucketKey] = None) -> PackedTenant:
     """Pad one resolved tenant to its bucket capacities. Runs under
-    ``enable_x64`` itself — the fills must concatenate at the operands'
+    ``jax.enable_x64`` itself — the fills must concatenate at the operands'
     own float64, exactly as runtime construction does."""
     if key is None:
         key = bucket_key_for(resolved)
-    with enable_x64():
+    with jax.enable_x64():
         return _pack_tenant(resolved, key)
 
 

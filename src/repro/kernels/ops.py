@@ -149,9 +149,10 @@ def int8_dequantize(q: jax.Array, scale: jax.Array, dtype=jnp.float32):
 
 
 def tiered_cost(month_cum, demand, bounds, rates):
-    T = month_cum.shape[0]
-    usable = _interpret_forced() or _on_tpu()
-    if usable and T % _tc.DEFAULT_BLOCK_T == 0:
+    """(T, P) tiered cost. Any shape takes the kernel wherever it is usable
+    (the kernel pads to whole blocks itself), so a TPU never drops to the
+    reference for an unaligned shape."""
+    if _interpret_forced() or _on_tpu():
         return _tc.tiered_cost(
             month_cum, demand, tuple(bounds), tuple(rates), interpret=not _on_tpu()
         )
@@ -164,9 +165,7 @@ def tiered_cost(month_cum, demand, bounds, rates):
 
 def tiered_cost_scan(cum0, demand, bounds, rates, reset):
     """Chunked K-hour tiered pricing; returns ``(costs (N, K), cum_out (N,))``."""
-    N = demand.shape[0]
-    usable = _interpret_forced() or _on_tpu()
-    if usable and N % 8 == 0:
+    if _interpret_forced() or _on_tpu():
         return _tc.tiered_cost_scan(
             cum0, demand, bounds, rates, reset, interpret=not _on_tpu()
         )

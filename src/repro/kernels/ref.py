@@ -289,8 +289,9 @@ def tiered_cost(
     rates: jax.Array,      # (n_tiers,)
 ) -> jax.Array:
     """(T, P) marginal tiered cost — oracle for the ``tiered_cost`` kernel."""
+    from repro.core.costmodel import tier_segment
+
     lo = month_cum.astype(jnp.float32)[..., None]
-    hi = lo + demand.astype(jnp.float32)[..., None]
+    d = demand.astype(jnp.float32)[..., None]
     prev = jnp.concatenate([jnp.zeros((1,), bounds.dtype), bounds[:-1]])
-    seg = jnp.clip(jnp.minimum(hi, bounds) - jnp.maximum(lo, prev), 0.0)
-    return jnp.sum(seg * rates, axis=-1)
+    return jnp.sum(tier_segment(lo, d, prev, bounds) * rates, axis=-1)

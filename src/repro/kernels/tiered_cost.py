@@ -19,6 +19,11 @@ link) and the grid tiles the ``(N, T)`` volume plane. The fleet engine
 (``repro.fleet.engine``) uses the pure-XLA twin
 (``tiered_cost_batched_ref``) by default — it fuses fine and supports f64 —
 and the Pallas path on TPU f32 runs where the segmentation loop dominates.
+
+Every wrapper zero-pads its operands up to whole (8, 128)-aligned blocks and
+slices the result back: zero volume against zero or positive bounds prices
+to exactly zero, so padding never changes a cost. Tiles stay well under the
+TPU's 16 MiB scoped-VMEM default at any problem size.
 """
 from __future__ import annotations
 
@@ -29,18 +34,48 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.costmodel import tier_segment
 
 DEFAULT_BLOCK_T = 512
+_BLOCK_P = 512          # pair-axis tile of ``tiered_cost`` (lanes)
+_BLOCK_N = 8            # link-axis tile of ``tiered_cost_batched`` (sublanes)
+DEFAULT_SCAN_BLOCK_N = 512  # link-axis tile of ``tiered_cost_scan`` (lanes)
+_LANE = 128
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _pad2(x: jax.Array, rows: int, cols: int) -> jax.Array:
+    r, c = x.shape
+    if (r, c) == (rows, cols):
+        return x
+    return jnp.pad(x, ((0, rows - r), (0, cols - c)))
+
+
+def _zero():
+    """Block index 0 as int32: a Python ``0`` turns int64 under ``enable_x64``
+    (the fleet engine traces its pricing there) and Mosaic rejects i64
+    indices."""
+    return jnp.int32(0)
+
+
+def _lane_block(n: int, block: int) -> int:
+    """Lane-axis tile: ``block`` (a multiple of 128) or, for a narrow axis,
+    the axis rounded up to whole lanes."""
+    return min(block, _round_up(n, _LANE))
 
 
 def _tiered_kernel(cum_ref, d_ref, o_ref, *, bounds: tuple, rates: tuple):
     lo = cum_ref[...].astype(jnp.float32)
-    hi = lo + d_ref[...].astype(jnp.float32)
+    d = d_ref[...].astype(jnp.float32)
     total = jnp.zeros_like(lo)
     prev = 0.0
     for b, r in zip(bounds, rates):
-        seg = jnp.clip(jnp.minimum(hi, b) - jnp.maximum(lo, prev), 0.0)
-        total = total + seg * r
+        total = total + tier_segment(lo, d, prev, b) * r
         prev = b
     o_ref[...] = total
 
@@ -54,22 +89,23 @@ def tiered_cost(
     block_t: int = DEFAULT_BLOCK_T,
     interpret: bool = False,
 ) -> jax.Array:
+    """(T, P) tiered cost, tiled over hours and pairs."""
     T, P = month_cum.shape
     assert demand.shape == (T, P)
-    assert T % block_t == 0, (T, block_t)
+    bp = _lane_block(P, _BLOCK_P)
+    Tp, Pp = _round_up(T, block_t), _round_up(P, bp)
     bounds = tuple(float(b) if np.isfinite(b) else 1e30 for b in bounds)
     rates = tuple(float(r) for r in rates)
-    return pl.pallas_call(
+    spec = pl.BlockSpec((block_t, bp), lambda i, j: (i, j))
+    out = pl.pallas_call(
         functools.partial(_tiered_kernel, bounds=bounds, rates=rates),
-        grid=(T // block_t,),
-        in_specs=[
-            pl.BlockSpec((block_t, P), lambda i: (i, 0)),
-            pl.BlockSpec((block_t, P), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_t, P), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, P), jnp.float32),
+        grid=(Tp // block_t, Pp // bp),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((Tp, Pp), jnp.float32),
         interpret=interpret,
-    )(month_cum, demand)
+    )(_pad2(month_cum, Tp, Pp), _pad2(demand, Tp, Pp))
+    return out[:T, :P]
 
 
 # ---------------------------------------------------------------------------
@@ -78,18 +114,17 @@ def tiered_cost(
 
 
 def _tiered_batched_kernel(cum_ref, d_ref, bounds_ref, rates_ref, o_ref):
-    lo = cum_ref[...].astype(jnp.float32)          # (1, block_t)
-    hi = lo + d_ref[...].astype(jnp.float32)
-    bounds = bounds_ref[...].astype(jnp.float32)   # (1, K)
+    lo = cum_ref[...].astype(jnp.float32)          # (block_n, block_t)
+    d = d_ref[...].astype(jnp.float32)
+    bounds = bounds_ref[...].astype(jnp.float32)   # (block_n, K)
     rates = rates_ref[...].astype(jnp.float32)
-    K = bounds.shape[-1]
-    prev = jnp.concatenate([jnp.zeros((1, 1), jnp.float32), bounds[:, : K - 1]], -1)
-    seg = jnp.clip(
-        jnp.minimum(hi[..., None], bounds[:, None, :])
-        - jnp.maximum(lo[..., None], prev[:, None, :]),
-        0.0,
-    )                                              # (1, block_t, K)
-    o_ref[...] = jnp.sum(seg * rates[:, None, :], axis=-1)
+    total = jnp.zeros_like(lo)
+    prev = jnp.zeros_like(bounds[:, :1])
+    for j in range(bounds.shape[-1]):              # K is small and static
+        b_j = bounds[:, j:j + 1]                   # (block_n, 1) over hours
+        total = total + tier_segment(lo, d, prev, b_j) * rates[:, j:j + 1]
+        prev = b_j
+    o_ref[...] = total
 
 
 def tiered_cost_batched(
@@ -105,20 +140,22 @@ def tiered_cost_batched(
     N, T = month_cum.shape
     K = bounds.shape[-1]
     assert demand.shape == (N, T) and bounds.shape == rates.shape == (N, K)
-    assert T % block_t == 0, (T, block_t)
-    return pl.pallas_call(
+    bn, bt = _BLOCK_N, _lane_block(T, block_t)
+    Np, Tp = _round_up(N, bn), _round_up(T, bt)
+    plane = pl.BlockSpec((bn, bt), lambda n, i: (n, i))
+    table = pl.BlockSpec((bn, K), lambda n, i: (n, _zero()))
+    out = pl.pallas_call(
         _tiered_batched_kernel,
-        grid=(N, T // block_t),
-        in_specs=[
-            pl.BlockSpec((1, block_t), lambda n, i: (n, i)),
-            pl.BlockSpec((1, block_t), lambda n, i: (n, i)),
-            pl.BlockSpec((1, K), lambda n, i: (n, 0)),
-            pl.BlockSpec((1, K), lambda n, i: (n, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_t), lambda n, i: (n, i)),
-        out_shape=jax.ShapeDtypeStruct((N, T), jnp.float32),
+        grid=(Np // bn, Tp // bt),
+        in_specs=[plane, plane, table, table],
+        out_specs=plane,
+        out_shape=jax.ShapeDtypeStruct((Np, Tp), jnp.float32),
         interpret=interpret,
-    )(month_cum, demand, bounds, rates)
+    )(
+        _pad2(month_cum, Np, Tp), _pad2(demand, Np, Tp),
+        _pad2(bounds, Np, K), _pad2(rates, Np, K),
+    )
+    return out[:N, :T]
 
 
 def tiered_cost_batched_ref(
@@ -136,32 +173,34 @@ def tiered_cost_batched_ref(
 
 
 def _tiered_scan_kernel(
-    cum_ref, d_ref, bounds_ref, rates_ref, reset_ref, o_ref, cum_out_ref
+    reset_ref, cum_ref, d_ref, bounds_ref, rates_ref, o_ref, cum_out_ref
 ):
-    K = d_ref.shape[1]
-    bounds = bounds_ref[...].astype(jnp.float32)     # (block_n, Kt)
+    # Hour-major tile: links on lanes, the chunk's K hours on sublanes, so
+    # the per-hour read/write is a dynamic SUBLANE row and the month-reset
+    # flag a scalar from SMEM — Mosaic needs lane offsets it can prove are
+    # multiples of 128, which a per-hour column index never is.
+    K = d_ref.shape[0]
+    bounds = bounds_ref[...].astype(jnp.float32)     # (Kt, block_n)
     rates = rates_ref[...].astype(jnp.float32)
-    Kt = bounds.shape[-1]
-    prev = jnp.concatenate(
-        [jnp.zeros((bounds.shape[0], 1), jnp.float32), bounds[:, : Kt - 1]], -1
-    )
+    Kt = bounds.shape[0]
 
     def body(k, cum):
         # ``cum`` is the month-to-date volume carried ACROSS the K inner
         # hours — it lives in VMEM/registers for the whole chunk; only the
-        # K cost columns and the final carry ever leave the tile.
-        cum = jnp.where(reset_ref[0, k] != 0, 0.0, cum)   # month boundary
-        hi = cum + d_ref[:, pl.dslice(k, 1)].astype(jnp.float32)
-        seg = jnp.clip(
-            jnp.minimum(hi, bounds) - jnp.maximum(cum, prev), 0.0
-        )                                                 # (block_n, Kt)
-        o_ref[:, pl.dslice(k, 1)] = jnp.sum(
-            seg * rates, axis=-1, keepdims=True
-        )
-        return hi
+        # K cost rows and the final carry ever leave the tile.
+        cum = jnp.where(reset_ref[k] != 0, 0.0, cum)      # month boundary
+        d = d_ref[pl.ds(k, 1), :].astype(jnp.float32)
+        total = jnp.zeros_like(cum)
+        prev = jnp.zeros_like(cum)
+        for j in range(Kt):
+            b_j = bounds[j:j + 1, :]
+            total = total + tier_segment(cum, d, prev, b_j) * rates[j:j + 1, :]
+            prev = b_j
+        o_ref[pl.ds(k, 1), :] = total
+        return cum + d
 
     cum_out_ref[...] = jax.lax.fori_loop(
-        0, K, body, cum_ref[...].astype(jnp.float32)
+        jnp.int32(0), jnp.int32(K), body, cum_ref[...].astype(jnp.float32)
     )
 
 
@@ -172,7 +211,7 @@ def tiered_cost_scan(
     rates: jax.Array,            # (N, Kt) per-link marginal rates (0 padding)
     reset: jax.Array,            # (K,) int/bool — hour k starts a new month
     *,
-    block_n: int = 8,
+    block_n: int = DEFAULT_SCAN_BLOCK_N,
     interpret: bool = False,
 ):
     """K-hour chunked tiered pricing with the tier carry resident in VMEM.
@@ -186,6 +225,9 @@ def tiered_cost_scan(
     Returns ``(costs (N, K) f32, cum_out (N,) f32)``; feeding ``cum_out``
     back as the next chunk's ``cum0`` chains chunks exactly.
 
+    The kernel runs hour-major (operands transposed to ``(K, N)``, links on
+    lanes in ``block_n`` tiles); the transposes are XLA ops around the call.
+
     f32 like the other Pallas kernels — this is the TPU throughput path;
     the runtime's jitted scan keeps XLA float64 pricing as the
     bit-exactness path (``tests/test_kernels.py`` sweeps this kernel
@@ -195,31 +237,34 @@ def tiered_cost_scan(
     Kt = bounds.shape[-1]
     assert cum0.shape == (N,) and bounds.shape == rates.shape == (N, Kt)
     assert reset.shape == (K,), (reset.shape, K)
-    assert N % block_n == 0, (N, block_n)
+    bn = _lane_block(N, block_n)
+    Np = _round_up(N, bn)
+    lanes = lambda a, rows: _pad2(a.T, rows, Np)
     costs, cum_out = pl.pallas_call(
         _tiered_scan_kernel,
-        grid=(N // block_n,),
+        grid=(Np // bn,),
         in_specs=[
-            pl.BlockSpec((block_n, 1), lambda n: (n, 0)),
-            pl.BlockSpec((block_n, K), lambda n: (n, 0)),
-            pl.BlockSpec((block_n, Kt), lambda n: (n, 0)),
-            pl.BlockSpec((block_n, Kt), lambda n: (n, 0)),
-            pl.BlockSpec((1, K), lambda n: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bn), lambda n: (_zero(), n)),
+            pl.BlockSpec((K, bn), lambda n: (_zero(), n)),
+            pl.BlockSpec((Kt, bn), lambda n: (_zero(), n)),
+            pl.BlockSpec((Kt, bn), lambda n: (_zero(), n)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n, K), lambda n: (n, 0)),
-            pl.BlockSpec((block_n, 1), lambda n: (n, 0)),
+            pl.BlockSpec((K, bn), lambda n: (_zero(), n)),
+            pl.BlockSpec((1, bn), lambda n: (_zero(), n)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, K), jnp.float32),
-            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((K, Np), jnp.float32),
+            jax.ShapeDtypeStruct((1, Np), jnp.float32),
         ],
         interpret=interpret,
     )(
-        cum0[:, None], demand, bounds, rates,
-        jnp.asarray(reset, jnp.int32)[None, :],
+        jnp.asarray(reset, jnp.int32),
+        lanes(cum0[:, None], 1), lanes(demand, K),
+        lanes(bounds, Kt), lanes(rates, Kt),
     )
-    return costs, cum_out[:, 0]
+    return costs[:, :N].T, cum_out[0, :N]
 
 
 def tiered_cost_scan_ref(cum0, demand, bounds, rates, reset):

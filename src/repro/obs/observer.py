@@ -2,7 +2,8 @@
 
 :class:`FleetObserver` is what :class:`repro.fleet.runtime.FleetRuntime`
 talks to when built with ``obs=ObsConfig(...)``: the runtime calls
-``record_step`` after every committed tick, ``record_drain`` whenever the
+``record_chunk`` after every committed step (one or more hours),
+``record_drain`` whenever the
 device metrics ring rode the packed D2H transfer home, and
 ``record_reroute`` / ``record_sync_domains`` on actuation-layer events. The
 observer fans these out to the trace recorder, the profiler, and the
@@ -218,30 +219,6 @@ class FleetObserver:
                     monitor=v.monitor, row=v.row, message=str(v),
                 )
             raise
-
-    def record_step(
-        self,
-        t: int,
-        out: dict,
-        *,
-        d_pair: np.ndarray,
-        demand_t: np.ndarray,
-        endo: bool,
-        h2d_bytes: int,
-        d2h_bytes: int,
-        dt_s: float,
-    ) -> None:
-        self.hours = t + 1
-        self.endo_seen |= endo
-        self.profiler.record(dt_s, h2d_bytes, d2h_bytes)
-        if self.trace is not None:
-            self.trace.observe_states(t, out["state"])
-        if self.billing is not None:
-            self.billing.on_step(t, out, d_pair)
-        if self.regret is not None:
-            self.regret.on_step(t, out)
-        if self.divergence is not None:
-            self.divergence.on_step(t, out, demand_t, endo)
 
     def record_chunk(
         self,

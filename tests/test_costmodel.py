@@ -5,6 +5,7 @@ from hypothesis import given
 from hypothesis import strategies as st
 import hypothesis.extra.numpy as hnp
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.costmodel import (
@@ -12,6 +13,10 @@ from repro.core.costmodel import (
     evaluate_schedule,
     hourly_cost_series,
     hourly_cost_series_jnp,
+    monthly_cumsum,
+    monthly_cumsum_np,
+    prefix_sum,
+    tier_segment,
     tiered_marginal_cost_np,
 )
 from repro.core.pricing import CostParams, flat_rate, make_scenario
@@ -117,3 +122,31 @@ def test_vectorized_tier_matches_scalar(start, add):
     vec = tiered_marginal_cost_np(tier, np.full(add.shape, start), add)
     ref = np.array([tier.marginal_cost(start, a) for a in add])
     np.testing.assert_allclose(vec, ref, rtol=1e-12, atol=1e-12)
+
+
+@pytest.mark.parametrize("hpm", [1, 7, 730])
+def test_device_prefix_sums_match_numpy_bitwise(hpm):
+    """The device month volumes and prefix sums add in numpy's order."""
+    rng = np.random.default_rng(hpm)
+    d = rng.lognormal(4.0, 2.0, size=(3, 2000))
+    with jax.enable_x64():
+        got = np.asarray(monthly_cumsum(jnp.asarray(d), hpm))
+        pref = np.asarray(prefix_sum(jnp.asarray(d)))
+    np.testing.assert_array_equal(got, monthly_cumsum_np(d, hpm))
+    np.testing.assert_array_equal(pref, np.cumsum(d, axis=-1))
+    assert (got[:, ::hpm] == 0).all()
+
+
+@given(
+    lo=st.floats(0, 1e7), d=st.floats(0, 1e4),
+    prev=st.floats(0, 2e5), width=st.floats(0, 2e5),
+)
+def test_tier_segment_is_the_clipped_overlap(lo, d, prev, width):
+    """``tier_segment`` is ``clip(min(lo + d, b) - max(lo, prev), 0)``, and
+    exactly ``d`` when the hour stays inside the tier."""
+    bound = prev + width
+    seg = float(tier_segment(lo, d, prev, bound, np))
+    want = max(0.0, min(lo + d, bound) - max(lo, prev))
+    assert seg == pytest.approx(want, rel=1e-12, abs=1e-9)
+    if prev <= lo and min(bound - lo, bound - prev) >= d:
+        assert seg == d

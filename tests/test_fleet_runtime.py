@@ -25,7 +25,6 @@ from hypothesis import strategies as st
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.pricing import CostParams, TieredRate
 from repro.fleet.plan import (
@@ -89,7 +88,7 @@ def _policies_for(arrays, out, rng):
     """One instance of each policy kind over ``arrays``, forecast included
     (predictions = noisy forward means, coefficients fitted on the runtime's
     own emitted series — how they were derived is irrelevant to exactness)."""
-    with enable_x64():
+    with jax.enable_x64():
         tp = arrays.toggle
         n, T = out["vpn_cost"].shape
         pred = _random_demand(rng, n, T) * rng.uniform(0.3, 1.2)
@@ -122,7 +121,7 @@ def test_streaming_steps_match_policy_scan_bit_for_bit(seed):
     n, T = 3, int(rng.integers(150, 400))
     fleet = fleet_from_params([_random_params(rng) for _ in range(n)])
     demand = _random_demand(rng, n, T)
-    with enable_x64():
+    with jax.enable_x64():
         arrays = fleet.stack(jnp.float64)
 
     # Prime with a reactive pass to get the emitted cost series.
@@ -138,7 +137,7 @@ def test_streaming_steps_match_policy_scan_bit_for_bit(seed):
         np.testing.assert_array_equal(out["vpn_cost"], vpn)
         np.testing.assert_array_equal(out["cci_cost"], cci)
         for i in range(n):
-            with enable_x64():
+            with jax.enable_x64():
                 row_pol = jax.tree.map(lambda a: a[i], pol)
                 ref = policy_scan(
                     row_pol, jnp.asarray(vpn[i]), jnp.asarray(cci[i])
@@ -161,7 +160,7 @@ def test_streaming_steps_match_policy_scan_bit_for_bit(seed):
 @pytest.mark.parametrize("seed", [0, 1])
 def test_streaming_matches_plan_fleet(seed):
     sc = build_fleet_scenario(8, horizon=600, history_hours=300, seed=seed)
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.fleet.stack(jnp.float64)
     hpm = sc.fleet.hours_per_month
 
@@ -173,7 +172,7 @@ def test_streaming_matches_plan_fleet(seed):
         out["vpn_cost"], np.asarray(plan["vpn_hourly"]), rtol=1e-12
     )
 
-    with enable_x64():
+    with jax.enable_x64():
         hy = make_policy("hysteresis", arrays.toggle)
     hplan = plan_fleet(arrays, sc.demand, policy=hy, hours_per_month=hpm)
     hout = FleetRuntime(arrays, policy=hy, hours_per_month=hpm).run(sc.demand)
@@ -194,7 +193,7 @@ def test_streaming_matches_plan_topology(seed):
     )
     routing = optimize_routing(sc.topo, sc.demand)
     hpm = sc.topo.hours_per_month
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(routing, jnp.float64)
 
     plan = plan_topology(arrays, sc.demand, hours_per_month=hpm)
@@ -239,7 +238,7 @@ def test_month_boundary_streaming():
     rng = np.random.default_rng(7)
     fleet = fleet_from_params([_random_params(rng) for _ in range(3)])
     demand = _random_demand(rng, 3, 260)
-    with enable_x64():
+    with jax.enable_x64():
         arrays = fleet.stack(jnp.float64)
     plan = plan_fleet(arrays, demand, hours_per_month=48)
     out = FleetRuntime(arrays, hours_per_month=48).run(demand)
@@ -290,7 +289,7 @@ def test_reroute_matches_offline_replay_bit_for_bit(seed):
     T = sc.demand.shape[1]
     s = int(rng.integers(50, T - 50))
     hpm = sc.topo.hours_per_month
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(r0, jnp.float64)
 
     base = FleetRuntime(arrays, hours_per_month=hpm).run(sc.demand)
@@ -331,7 +330,7 @@ def test_obs_on_off_decisions_bit_exact(seed):
     T = sc.demand.shape[1]
     s = int(rng.integers(40, T - 40))
     hpm = sc.topo.hours_per_month
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(r0, jnp.float64)
 
     base = FleetRuntime(arrays, hours_per_month=hpm).run(sc.demand)
@@ -379,7 +378,7 @@ def test_step_many_chunking_bit_exact(seed):
     T = sc.demand.shape[1]
     s = 168  # chunk boundary for every K in {1, 7, 24} (168 = 7 * 24)
     hpm = sc.topo.hours_per_month
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(r0, jnp.float64)
 
     fields = ("x", "state", "r_vpn", "r_cci", "vpn_cost", "cci_cost", "cost")
@@ -421,7 +420,7 @@ def test_step_many_chunking_bit_exact(seed):
                         got[f], want[f], err_msg=f"{ctx}:{f}"
                     )
                 # Carried billing prefixes resync identically at boundaries.
-                for f in ("vpn_pref", "cci_pref", "dcum", "dcum_month"):
+                for f in ("vpn_pref", "cci_pref", "dcum", "month_vol"):
                     np.testing.assert_array_equal(
                         getattr(rt2._state, f), getattr(want_state, f),
                         err_msg=f"{ctx}:{f}",
@@ -440,7 +439,7 @@ def test_replay_single_segment_is_plan_topology():
     sc = build_topology_scenario(8, n_facilities=3, horizon=400, seed=2)
     r0 = optimize_routing(sc.topo, sc.demand)
     hpm = sc.topo.hours_per_month
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(r0, jnp.float64)
     plan = plan_topology(arrays, sc.demand, hours_per_month=hpm)
     rep = replay_plan_topology(arrays, sc.demand, [(0, r0)], hours_per_month=hpm)
@@ -526,7 +525,7 @@ def test_live_forecast_mode_matches_pinned_replay():
 
     sc = build_fleet_scenario(6, horizon=400, history_hours=300, seed=3)
     hpm = sc.fleet.hours_per_month
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.fleet.stack(jnp.float64)
     pol, fc = streaming_forecast_policy(
         arrays, sc.history, steps=30, hours_per_month=hpm
@@ -541,7 +540,7 @@ def test_live_forecast_mode_matches_pinned_replay():
         clip(sc.history), clip(sc.demand),
         forecast_horizon_hours(arrays.toggle), steps=30,
     )
-    with enable_x64():
+    with jax.enable_x64():
         replay = forecast_gated_policy(
             arrays.toggle, pred, margin=0.05, cost_coef=np.asarray(pol.cost_coef)
         )
@@ -552,7 +551,7 @@ def test_live_forecast_mode_matches_pinned_replay():
 def test_streaming_forecast_requires_cost_coef():
     rng = np.random.default_rng(0)
     fleet = fleet_from_params([_random_params(rng)])
-    with enable_x64():
+    with jax.enable_x64():
         arrays = fleet.stack(jnp.float64)
         pol = forecast_gated_policy(arrays.toggle, np.zeros((1, 100)))
     with pytest.raises(AssertionError, match="cost_coef"):

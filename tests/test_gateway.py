@@ -11,8 +11,8 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.fleet.policy import (
     fit_cost_coef,
@@ -53,7 +53,7 @@ def _topology_tenant(n_pairs, horizon, seed, *, policy_kind="reactive", rng=None
     routing = optimize_routing(sc.topo, sc.demand)
     policy = None
     if policy_kind != "reactive":
-        with enable_x64():
+        with jax.enable_x64():
             arrays = sc.topo.stack(routing, jnp.float64)
             base = FleetRuntime(
                 arrays, hours_per_month=sc.topo.hours_per_month

@@ -302,11 +302,12 @@ def test_tiered_cost_scan_matches_ref(K):
 
 
 def test_ops_tiered_cost_scan_dispatch():
-    """ops wrapper falls back to the XLA twin when N is not tile-aligned."""
+    """Off-TPU and outside ``force_interpret`` the ops wrapper takes the XLA
+    twin."""
     from repro.kernels.tiered_cost import tiered_cost_scan_ref
 
     rng = np.random.default_rng(12)
-    N, K, Kt = 5, 6, 3  # N % 8 != 0 -> ref path off-TPU
+    N, K, Kt = 5, 6, 3
     cum0 = jnp.asarray(rng.uniform(0, 100, N), jnp.float32)
     d = jnp.asarray(rng.uniform(0, 50, (N, K)), jnp.float32)
     b = np.sort(rng.uniform(50, 500, (N, Kt)), axis=1)
@@ -318,3 +319,34 @@ def test_ops_tiered_cost_scan_dispatch():
     want, cum_want = tiered_cost_scan_ref(cum0, d, bounds, rates, reset)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(cum_out), np.asarray(cum_want), rtol=1e-6)
+
+
+def test_ops_tiered_cost_kernels_pad_unaligned_shapes():
+    """Shapes that fit no block still take the kernels (padded to whole
+    blocks), and agree with the XLA twins."""
+    from repro.core.pricing import GCP_EGRESS_PREMIUM as tier
+    from repro.kernels.tiered_cost import tiered_cost_scan_ref
+
+    rng = np.random.default_rng(13)
+    d = jnp.asarray(rng.uniform(0, 100, size=(300, 3)), jnp.float32)
+    cum = jnp.cumsum(d, axis=0) - d
+    N, K, Kt = 5, 6, 3
+    cum0 = jnp.asarray(rng.uniform(0, 100, N), jnp.float32)
+    dd = jnp.asarray(rng.uniform(0, 50, (N, K)), jnp.float32)
+    b = np.sort(rng.uniform(50, 500, (N, Kt)), axis=1)
+    b[:, -1] = 1e30
+    bounds = jnp.asarray(b, jnp.float32)
+    rates = jnp.asarray(rng.uniform(0.01, 0.2, (N, Kt)), jnp.float32)
+    reset = jnp.asarray([0, 0, 1, 0, 0, 0], jnp.int32)
+    with ops.force_interpret():
+        out = ops.tiered_cost(cum, d, tier.bounds_gb, tier.rates)
+        sc, sc_cum = ops.tiered_cost_scan(cum0, dd, bounds, rates, reset)
+    want = ref.tiered_cost(
+        cum, d,
+        jnp.asarray([b if np.isfinite(b) else 1e30 for b in tier.bounds_gb], jnp.float32),
+        jnp.asarray(tier.rates, jnp.float32),
+    )
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6)
+    want_sc, want_cum = tiered_cost_scan_ref(cum0, dd, bounds, rates, reset)
+    np.testing.assert_allclose(np.asarray(sc), np.asarray(want_sc), rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(sc_cum), np.asarray(want_cum), rtol=1e-6)

@@ -24,8 +24,8 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 import repro.fleet.runtime as runtime_mod
 from repro.core.pricing import flat_rate
@@ -96,7 +96,7 @@ def test_one_hop_pathspec_degenerates_to_pairspec(seed, long_gb_hr, policy):
     outs = []
     for topo in (sc.topo, _demote_paths(sc.topo)):
         if policy == "forecast":
-            with enable_x64():
+            with jax.enable_x64():
                 arrays = topo.stack(routing, jnp.float64)
             fpol = forecast_topology_policy(arrays, sc.demand, None, steps=24)
             outs.append(
@@ -235,7 +235,7 @@ def test_reroute_hop_depth_swap_zero_recompile(relay_sc, relay_routing):
     )
 
     # Decision-bit-exactness vs the offline replay oracle.
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(direct.pad_to(bound), jnp.float64)
     replay = replay_plan_topology(
         arrays, sc.demand[:, :T],

@@ -21,8 +21,8 @@ import json
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.togglecci import OFF, ON, WAITING
 from repro.fleet.plan import (
@@ -124,7 +124,7 @@ def test_ring_matches_numpy_reference(topology, pred):
     ticks[0]["vpn"][0] = edges[2]
     ticks[0]["x"][0] = 0
 
-    with enable_x64():
+    with jax.enable_x64():
         ring = init_ring(M, cap, B, K)
         for tk in ticks:
             ring = update_ring(
@@ -190,7 +190,7 @@ def test_ring_reset_carries_prev_state_across_drains():
             hour, np.asarray(flatten_ring(ring)), cap=cap, n_bins=B, n_tiers=K
         )
 
-    with enable_x64():
+    with jax.enable_x64():
         ring = init_ring(M, cap, B, K)
         ring = upd(ring, [WAITING, OFF, OFF])   # row 0 requests
         ring = upd(ring, [WAITING, OFF, OFF])
@@ -391,7 +391,7 @@ def test_regret_monitor_oracle_ratio_fires():
 def test_calibration_monitor_fires_on_biased_forecast():
     rt, sc = _fleet_rt(None)  # prime a reactive pass for the coefficients
     base = rt.run(sc.demand)
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.fleet.stack(jnp.float64)
         coef = np.asarray(fit_cost_coef(
             jnp.asarray(sc.demand), jnp.asarray(base["vpn_cost"]),

@@ -15,8 +15,8 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.costmodel import HourlyCosts, hourly_cost_series
 from repro.core.pricing import CostParams, TieredRate, flat_rate
@@ -106,7 +106,7 @@ def test_reactive_policy_reproduces_plan_fleet(seed):
     """plan_fleet with an EXPLICIT ReactivePolicy == the per-link float64
     reference == plan_fleet with the default policy (all bit-for-bit)."""
     sc = build_fleet_scenario(8, horizon=HORIZON, seed=seed)
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.fleet.stack(jnp.float64)
         pol = reactive_policy(arrays.toggle, renew_in_chunks=False)
     explicit = plan_fleet(arrays, sc.demand, policy=pol,
@@ -126,7 +126,7 @@ def test_reactive_policy_reproduces_plan_topology(seed):
     port cost series (the plan_topology_reference policy contract)."""
     sc = build_topology_scenario(10, n_facilities=3, horizon=HORIZON, seed=seed)
     routing = optimize_routing(sc.topo, sc.demand)
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(routing, jnp.float64)
         pol = reactive_policy(arrays.toggle)
     plan = plan_topology(arrays, sc.demand, policy=pol,
@@ -251,7 +251,7 @@ def test_forecast_policy_through_plan_fleet():
     params, d = _step_case()
     fleet = fleet_from_params([params, params])
     demand = np.stack([d, d])
-    with enable_x64():
+    with jax.enable_x64():
         arrays = fleet.stack(jnp.float64)
         pred = np.stack([
             _true_forward_mean(row, params.D + params.T_cci) for row in demand
@@ -405,7 +405,7 @@ def test_report_forecast_and_refinement_columns():
     plan = plan_topology(sc.topo, sc.demand, routing=routing)
     from repro.fleet.plan import forecast_topology_policy
 
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(routing, jnp.float64)
     fpol = forecast_topology_policy(arrays, sc.demand, sc.history, steps=60)
     fplan = plan_topology(arrays, sc.demand, policy=fpol,

@@ -20,7 +20,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.fleet.plan import (
     build_topology_report,
@@ -68,7 +67,7 @@ def test_from_indices_round_trip():
 def test_operand_round_trip_and_padding():
     p = RoutingPlan(paths=((0,), (1, 2), (0,)), n_ports=3)
     assert p.total_hops == 4 and p.n_legs == 4 and p.hop_depth == 2
-    with enable_x64():
+    with jax.enable_x64():
         op = p.operand(jnp.float64)
         assert isinstance(op, RoutingOperand)
         back = RoutingPlan.from_operand(op, 3, provenance="rt")
@@ -142,7 +141,7 @@ def _digest(x):
 
 
 def _case_stack(sc, routing):
-    with enable_x64():
+    with jax.enable_x64():
         op = sc.topo.stack(routing, jnp.float64).routing
     return {f: np.asarray(getattr(op, f)) for f in op._fields}
 
@@ -154,7 +153,7 @@ def _case_plan_topology(sc, routing):
 
 def _case_replay(sc, routing):
     plan = optimize_routing(sc.topo, sc.demand)
-    with enable_x64():
+    with jax.enable_x64():
         arrays = sc.topo.stack(plan, jnp.float64)
     out = replay_plan_topology(
         arrays, sc.demand, [(0, routing)],

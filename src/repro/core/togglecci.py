@@ -179,7 +179,8 @@ def window_sums(hourly: jax.Array, h) -> jax.Array:
     acc = jnp.result_type(float)
     v = hourly.astype(acc)
     T = v.shape[0]
-    pref = jnp.concatenate([jnp.zeros(1, acc), prefix_sum(v)])
+    with jax.named_scope("prefix_scan"):
+        pref = jnp.concatenate([jnp.zeros(1, acc), prefix_sum(v)])
     t_idx = jnp.arange(T)
     lo = jnp.maximum(0, t_idx - h)
     return pref[t_idx] - pref[lo]

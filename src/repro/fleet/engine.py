@@ -53,6 +53,7 @@ from repro.core.costmodel import (
 )
 from repro.core.togglecci import run_togglecci
 from repro.kernels.tiered_cost import tiered_cost_batched
+from repro.obs.profile import span
 
 from .policy import make_policy, policy_scan
 from .routing import RoutingOperand, RoutingPlan, as_routing_plan
@@ -77,13 +78,15 @@ def _plan_outputs(policy, d, vpn, cci) -> Dict[str, jax.Array]:
     hours ride VPN (paper Fig. 11's "misses the first D")."""
     out = _run_policies(policy, d, vpn, cci)
     T = d.shape[1]
-    cci_live = jnp.arange(T)[None, :] >= policy.toggle.D[:, None]
-    static_cci = jnp.sum(jnp.where(cci_live, cci, vpn), axis=1)
+    with jax.named_scope("comparators"):
+        cci_live = jnp.arange(T)[None, :] >= policy.toggle.D[:, None]
+        static_cci = jnp.sum(jnp.where(cci_live, cci, vpn), axis=1)
+        static_vpn = jnp.sum(vpn, axis=1)
     return {
         "x": out["x"],                     # (rows, T) 0/1 decision sequences
         "state": out["state"],             # (rows, T) FSM states
         "toggle_cost": out["total_cost"],  # (rows,)
-        "static_vpn": jnp.sum(vpn, axis=1),
+        "static_vpn": static_vpn,
         "static_cci": static_cci,
         "vpn_hourly": vpn,
         "cci_hourly": cci,
@@ -112,22 +115,25 @@ def _pair_stage(arrays, demand: jax.Array, *, hours_per_month: int,
     f = jnp.result_type(float)
     topology = isinstance(arrays, TopologyArrays)
     cap = arrays.pair_capacity if topology else arrays.capacity
-    d = jnp.minimum(demand.astype(f), cap[:, None])                   # (P, T)
-    month_cum = monthly_cumsum(d, hours_per_month)
-    if use_pallas:
-        # f32 kernel path (the kernel pads to whole blocks itself);
-        # interpreted off-TPU.
-        f32 = lambda a: a.astype(jnp.float32)
-        vpn_transfer = tiered_cost_batched(
-            f32(month_cum), f32(d),
-            f32(arrays.tier_bounds), f32(arrays.tier_rates),
-            interpret=jax.default_backend() != "tpu",
-        ).astype(f)
-    else:
-        vpn_transfer = tiered_marginal_cost_tables(
-            month_cum, d, arrays.tier_bounds, arrays.tier_rates
-        )
-    return d, arrays.L_vpn[:, None] + vpn_transfer
+    with jax.named_scope("pricing"):
+        d = jnp.minimum(demand.astype(f), cap[:, None])               # (P, T)
+    with jax.named_scope("calendar_scan"):
+        month_cum = monthly_cumsum(d, hours_per_month)
+    with jax.named_scope("pricing"):
+        if use_pallas:
+            # f32 kernel path (the kernel pads to whole blocks itself);
+            # interpreted off-TPU.
+            f32 = lambda a: a.astype(jnp.float32)
+            vpn_transfer = tiered_cost_batched(
+                f32(month_cum), f32(d),
+                f32(arrays.tier_bounds), f32(arrays.tier_rates),
+                interpret=jax.default_backend() != "tpu",
+            ).astype(f)
+        else:
+            vpn_transfer = tiered_marginal_cost_tables(
+                month_cum, d, arrays.tier_bounds, arrays.tier_rates
+            )
+        return d, arrays.L_vpn[:, None] + vpn_transfer
 
 
 def _route_stage(arrays, routing, d_pair, vpn_pair):
@@ -199,7 +205,10 @@ def routed_cost_series(
         arrays, demand, hours_per_month=hours_per_month, use_pallas=use_pallas
     )
     routing = arrays.routing if isinstance(arrays, TopologyArrays) else None
-    d_row, vpn, cci, n_pairs = _route_stage(arrays, routing, d_pair, vpn_pair)
+    with jax.named_scope("route"):
+        d_row, vpn, cci, n_pairs = _route_stage(
+            arrays, routing, d_pair, vpn_pair
+        )
     return RoutedSeries(d_pair, d_row, vpn, cci, n_pairs)
 
 
@@ -264,7 +273,9 @@ def plan_fleet(
       dict of per-link arrays — see ``_build_plan_fn`` (plus ``demand``, an
       alias of ``pair_demand`` kept for the per-link view).
     """
-    with jax.enable_x64():
+    # Host work only: the plan runs on after the return, and the caller's
+    # fetch of its outputs lies outside the ``fleet.plan`` span.
+    with span("fleet.plan"), jax.enable_x64():
         kind = "reactive"
         if isinstance(fleet, FleetSpec):
             hours_per_month = fleet.hours_per_month
@@ -273,10 +284,14 @@ def plan_fleet(
         else:
             arrays = fleet
         if policy is None:
-            policy = make_policy(
-                kind, arrays.toggle, renew_in_chunks=renew_in_chunks
-            )
-        out = dict(_run_plan(arrays, demand, policy, hours_per_month, use_pallas))
+            with span("fleet.plan.policy"):
+                policy = make_policy(
+                    kind, arrays.toggle, renew_in_chunks=renew_in_chunks
+                )
+        with span("fleet.plan.dispatch"):
+            out = dict(_run_plan(
+                arrays, demand, policy, hours_per_month, use_pallas
+            ))
         out["demand"] = out["pair_demand"]
         return out
 

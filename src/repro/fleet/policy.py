@@ -323,17 +323,19 @@ def policy_scan(policy, vpn_hourly: jax.Array, cci_hourly: jax.Array, *, demand=
       ``total_cost`` scalar — the exact contract the planners consume.
     """
     tp = policy.toggle
-    r_vpn_tr = window_sums(vpn_hourly, tp.h)
-    r_cci_tr = window_sums(cci_hourly, tp.h)
+    with jax.named_scope("window_sums"):
+        r_vpn_tr = window_sums(vpn_hourly, tp.h)
+        r_cci_tr = window_sums(cci_hourly, tp.h)
     extras = policy.features(demand, vpn_hourly, cci_hourly)
 
     def step(carry, xs):
         window, ex = xs
         return policy.step(carry, window, ex)
 
-    _, (x, state_tr) = jax.lax.scan(
-        step, policy.init_carry(), ((r_vpn_tr, r_cci_tr), extras)
-    )
+    with jax.named_scope("fsm_scan"):
+        _, (x, state_tr) = jax.lax.scan(
+            step, policy.init_carry(), ((r_vpn_tr, r_cci_tr), extras)
+        )
     acc = r_vpn_tr.dtype
     total = jnp.sum(
         jnp.where(x == 1, cci_hourly.astype(acc), vpn_hourly.astype(acc))

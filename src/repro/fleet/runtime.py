@@ -46,7 +46,6 @@ studies treat as exogenous.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, NamedTuple, Optional, Sequence, Union
 
 import numpy as np
@@ -57,6 +56,7 @@ import jax.numpy as jnp
 from repro.core.costmodel import tier_segment
 from repro.core.planner import COMPRESS_RATIO, collective_mode
 from repro.obs.metrics import flatten_ring, init_ring, reset_ring, update_ring
+from repro.obs.profile import count, span
 
 from .policy import ForecastGatedPolicy, make_policy, predicted_mode_costs
 from .routing import RoutingOperand, RoutingPlan, as_routing_plan
@@ -276,27 +276,29 @@ def _build_step_many(
         pre_c = demand_block[nd + K * M:nd + 2 * K * M].reshape(K, M)
 
         # --- pricing planes (demand-only; the offline formulation) --------
-        cap = arrays.pair_capacity if topology else arrays.capacity
-        d_pair = jnp.minimum(d_cols.astype(f), cap[None, :])  # (K, P)
-        if endo:
-            d_cci_raw = jnp.minimum(
-                demand_block[K * P:2 * K * P].reshape(P, K).T.astype(f),
-                cap[None, :],
-            )
-        else:
-            d_cci_raw = d_pair
+        with jax.named_scope("pricing"):
+            cap = arrays.pair_capacity if topology else arrays.capacity
+            d_pair = jnp.minimum(d_cols.astype(f), cap[None, :])  # (K, P)
+            if endo:
+                d_cci_raw = jnp.minimum(
+                    demand_block[K * P:2 * K * P].reshape(P, K).T.astype(f),
+                    cap[None, :],
+                )
+            else:
+                d_cci_raw = d_pair
 
         # Billing calendar: sequential month-boundary resets over (P,)
         # vectors (one f64 add + one select per hour, as prefix_sum adds; a
         # parallel cumsum would reassociate, this does not).
-        def cal_body(carry, d_k):
-            dcum, mv, tk = carry
-            mv = jnp.where(tk % hpm == 0, jnp.zeros_like(mv), mv)
-            return (dcum + d_k, mv + d_k, tk + 1), mv
+        with jax.named_scope("calendar_scan"):
+            def cal_body(carry, d_k):
+                dcum, mv, tk = carry
+                mv = jnp.where(tk % hpm == 0, jnp.zeros_like(mv), mv)
+                return (dcum + d_k, mv + d_k, tk + 1), mv
 
-        (dcum, month_vol, _), month_cum = jax.lax.scan(
-            cal_body, (dcum, month_vol, t0), d_pair
-        )                                                     # (K, P)
+            (dcum, month_vol, _), month_cum = jax.lax.scan(
+                cal_body, (dcum, month_vol, t0), d_pair
+            )                                                     # (K, P)
 
         # Tier pricing, unrolled over the Kt tier columns so every
         # intermediate is a fusible (K, P) plane. This is the same
@@ -305,73 +307,78 @@ def _build_step_many(
         # so the bits match the per-tick path exactly; the broadcast
         # (K, P, Kt) temps of the table formulation stay unfused on
         # XLA:CPU and cost ~15MB of memory traffic per chunk.
-        bounds = arrays.tier_bounds.astype(f)                 # (P, Kt)
-        rates = arrays.tier_rates.astype(f)
-        vpn_transfer = jnp.zeros((), f)
-        prev_b = jnp.zeros((bounds.shape[0],), f)
-        for j in range(bounds.shape[-1]):
-            seg_j = tier_segment(
-                month_cum, d_pair, prev_b[None, :], bounds[None, :, j]
-            )
-            # Same FMA guard as tiered_marginal_cost_tables: the where()
-            # keeps LLVM from contracting the product into the fold add
-            # (contraction is per-fusion-context, so chunked bits would
-            # drift from per-tick bits).
-            vpn_transfer = vpn_transfer + jnp.where(
-                seg_j > 0, seg_j * rates[None, :, j], 0.0
-            )
-            prev_b = bounds[:, j]
-        if topology:
-            vpn_pair = arrays.L_vpn[None, :] + vpn_transfer   # (K, P)
-            # Same leg-list aggregation as the per-tick step, vmapped over
-            # the chunk's K hour planes (each hour is the identical
-            # per-element gather/weight/segment chain — bit parity holds).
-            lp, lm = routing.leg_pair, routing.leg_port
-            vw, aw = routing.vpn_w, routing.attach_w
-            seg = jax.vmap(
-                lambda v: jax.ops.segment_sum(v, lm, num_segments=M)
-            )
-            vpn_t = seg(vpn_pair[:, lp] * vw[None, :])        # (K, M)
-            d_bill = jnp.minimum(
-                seg(d_cci_raw[:, lp] * aw[None, :]),
-                arrays.port_capacity[None, :],
-            )
-            n_pairs = jax.ops.segment_sum(aw, lm, num_segments=M)  # (M,)
-            cci_t = (
-                arrays.L_cci[None, :] + arrays.V_cci[None, :] * n_pairs[None, :]
-                + arrays.c_cci[None, :] * d_bill
-            )
-            d_row = jnp.minimum(
-                seg(d_pair[:, lp] * aw[None, :]),
-                arrays.port_capacity[None, :],
-            )
-        else:
-            vpn_t = arrays.L_vpn[None, :] + vpn_transfer
-            cci_t = (
-                (arrays.L_cci + arrays.V_cci)[None, :]
-                + arrays.c_cci[None, :] * d_cci_raw
-            )
-            d_row = d_pair
+        with jax.named_scope("pricing"):
+            bounds = arrays.tier_bounds.astype(f)                 # (P, Kt)
+            rates = arrays.tier_rates.astype(f)
+            vpn_transfer = jnp.zeros((), f)
+            prev_b = jnp.zeros((bounds.shape[0],), f)
+            for j in range(bounds.shape[-1]):
+                seg_j = tier_segment(
+                    month_cum, d_pair, prev_b[None, :], bounds[None, :, j]
+                )
+                # Same FMA guard as tiered_marginal_cost_tables: the where()
+                # keeps LLVM from contracting the product into the fold add
+                # (contraction is per-fusion-context, so chunked bits would
+                # drift from per-tick bits).
+                vpn_transfer = vpn_transfer + jnp.where(
+                    seg_j > 0, seg_j * rates[None, :, j], 0.0
+                )
+                prev_b = bounds[:, j]
+            if topology:
+                vpn_pair = arrays.L_vpn[None, :] + vpn_transfer   # (K, P)
+                # Same leg-list aggregation as the per-tick step, vmapped
+                # over the chunk's K hour planes (each hour is the identical
+                # per-element gather/weight/segment chain — bit parity
+                # holds).
+                lp, lm = routing.leg_pair, routing.leg_port
+                vw, aw = routing.vpn_w, routing.attach_w
+                seg = jax.vmap(
+                    lambda v: jax.ops.segment_sum(v, lm, num_segments=M)
+                )
+                vpn_t = seg(vpn_pair[:, lp] * vw[None, :])        # (K, M)
+                d_bill = jnp.minimum(
+                    seg(d_cci_raw[:, lp] * aw[None, :]),
+                    arrays.port_capacity[None, :],
+                )
+                n_pairs = jax.ops.segment_sum(aw, lm, num_segments=M)  # (M,)
+                cci_t = (
+                    arrays.L_cci[None, :]
+                    + arrays.V_cci[None, :] * n_pairs[None, :]
+                    + arrays.c_cci[None, :] * d_bill
+                )
+                d_row = jnp.minimum(
+                    seg(d_pair[:, lp] * aw[None, :]),
+                    arrays.port_capacity[None, :],
+                )
+            else:
+                vpn_t = arrays.L_vpn[None, :] + vpn_transfer
+                cci_t = (
+                    (arrays.L_cci + arrays.V_cci)[None, :]
+                    + arrays.c_cci[None, :] * d_cci_raw
+                )
+                d_row = d_pair
 
         # --- toggle window planes -----------------------------------------
         # Start-of-hour prefix snapshots (the exclusive-prefix convention:
         # snapshot BEFORE the hour's cost is absorbed), then window sums
         # against the hoisted ring reads.
-        def pref_body(carry, vc):
-            vpn_pref, cci_pref = carry
-            v_k, c_k = vc
-            return (vpn_pref + v_k, cci_pref + c_k), (vpn_pref, cci_pref)
+        with jax.named_scope("prefix_scan"):
+            def pref_body(carry, vc):
+                vpn_pref, cci_pref = carry
+                v_k, c_k = vc
+                return (vpn_pref + v_k, cci_pref + c_k), (vpn_pref, cci_pref)
 
-        (vpn_pref, cci_pref), (snap_v, snap_c) = jax.lax.scan(
-            pref_body, (vpn_pref, cci_pref), (vpn_t, cci_t)
-        )                                                     # snaps (K, M)
-        lo = jnp.maximum(0, (t0 + ks)[:, None] - h[None, :])  # (K, M)
-        in_chunk = lo >= t0
-        jj = jnp.clip(lo - t0, 0, K - 1)
-        in_v = jnp.take_along_axis(snap_v, jj, axis=0)
-        in_c = jnp.take_along_axis(snap_c, jj, axis=0)
-        r_vpn = snap_v - jnp.where(in_chunk, in_v, pre_v)     # (K, M)
-        r_cci = snap_c - jnp.where(in_chunk, in_c, pre_c)
+            (vpn_pref, cci_pref), (snap_v, snap_c) = jax.lax.scan(
+                pref_body, (vpn_pref, cci_pref), (vpn_t, cci_t)
+            )                                                 # snaps (K, M)
+        with jax.named_scope("window_sums"):
+            lo = jnp.maximum(0, (t0 + ks)[:, None] - h[None, :])  # (K, M)
+            in_chunk = lo >= t0
+            jj = jnp.clip(lo - t0, 0, K - 1)
+            in_v = jnp.take_along_axis(snap_v, jj, axis=0)
+            in_c = jnp.take_along_axis(snap_c, jj, axis=0)
+            r_vpn = snap_v - jnp.where(in_chunk, in_v, pre_v)     # (K, M)
+            r_cci = snap_c - jnp.where(in_chunk, in_c, pre_c)
 
         # --- forecast gate features ---------------------------------------
         pred_cols = None                                      # (K, M)
@@ -433,9 +440,10 @@ def _build_step_many(
                 )
             return (fsm, ssm_h, ring, pred_live), ys_t
 
-        (fsm, ssm_h, ring, pred_live), ys_t = jax.lax.scan(
-            body, (fsm, ssm_h, ring, pred_live), xs, length=K
-        )
+        with jax.named_scope("fsm_scan"):
+            (fsm, ssm_h, ring, pred_live), ys_t = jax.lax.scan(
+                body, (fsm, ssm_h, ring, pred_live), xs, length=K
+            )
 
         # --- commit + assemble --------------------------------------------
         # Ring writes are the HOST's job (its replay loop updates the numpy
@@ -716,8 +724,6 @@ class FleetRuntime:
                                  self.obs is not None, drain, K),
                 donate_argnums=(7, 10) if self.obs is not None else (10,),
             ))
-            if self.obs is not None:
-                self.obs.note_compile()
         return fn
 
     def _device_seq(self):
@@ -819,17 +825,118 @@ class FleetRuntime:
         cadence, or break the stream at the boundary): drains then fire at
         the same hours with bit-identical windows, riding the chunk's
         packed D2H transfer.
+
+        The call is the ``fleet.step`` span of :mod:`repro.obs.profile`, cut
+        into ``pack``, ``dispatch``, ``wait``, ``fetch`` and ``mirror``.
         """
-        t0 = time.perf_counter() if self.obs is not None else 0.0
-        st = self._state
+        with span("fleet.step"):
+            with span("fleet.step.pack"):
+                st = self._state
+                t = st.t
+                P = self.n_demand_rows
+                d = np.asarray(demand_block, np.float64)
+                assert d.ndim == 2 and d.shape[0] == P, (
+                    f"demand_block must be (rows, K) = ({P}, K), got {d.shape}"
+                )
+                K = d.shape[1]
+                assert K >= 1, K
+                endo = cci_demand_block is not None
+                block = self._pack(st, d, cci_demand_block)
+                drain = False
+                if self.obs is not None:
+                    cadence = self.obs.cadence
+                    boundary = ((t // cadence) + 1) * cadence  # 1st drain > t
+                    assert boundary >= t + K, (
+                        f"obs drain cadence {cadence} falls mid-chunk (hour "
+                        f"{boundary} inside ({t}, {t + K})): chunk ends must "
+                        f"align with the drain cadence — pick K dividing the "
+                        f"cadence, or step() across the boundary"
+                    )
+                    drain = boundary == t + K
+            with span("fleet.step.dispatch"):
+                fn = self._step_many_fn(endo, drain, K)
+                with jax.enable_x64():
+                    fsm, ssm_h, t_dev, ring, seq, planes, drain_vec = fn(
+                        self.arrays, self.policy, self._fc, st.fsm, st.ssm_h,
+                        st.t_dev, st.routing, st.metrics, self._obs_edges,
+                        self._hpm_dev, self._device_seq(),
+                        jax.device_put(block),
+                    )
+                self._dev_seq = seq
+            with span("fleet.step.wait"):
+                jax.block_until_ready(planes)
+            with span("fleet.step.fetch"):
+                # Every D2H copy of the call, one blocking copy each: the
+                # (K, rows) planes, the four float64 accumulators the host
+                # mirrors, and the drained metrics ring.
+                fetched = [np.asarray(a) for a in (*planes, *seq[:4])]
+                if drain:
+                    fetched.append(np.asarray(drain_vec))
+                count("fleet.step.d2h_arrays", len(fetched))
+                count("fleet.step.h2d_bytes", block.nbytes)
+                count("fleet.step.d2h_bytes", sum(a.nbytes for a in fetched))
+
+            with span("fleet.step.mirror"):
+                it = iter(fetched)                          # (K, rows) planes
+                x = next(it).astype(np.int64)
+                state = next(it).astype(np.int64)
+                vpn_t = next(it)
+                cci_t = next(it)
+                d_pair = next(it)
+                if self.pred_source == "live":
+                    pred_block = next(it)
+                r_vpn = next(it)
+                r_cci = next(it)
+                snap_v = next(it)
+                snap_c = next(it)
+
+                # Mirror the device's sequential scans into the host
+                # accumulators. ``snap[k]`` is the prefix BEFORE hour t+k
+                # (the ring-snapshot / exclusive-prefix convention); the seq
+                # carry holds the post-chunk accumulators. K > hbuf: only
+                # the last hbuf slots survive, earlier ones are rewritten.
+                tks = t + np.arange(K)
+                w = min(K, self.hbuf)
+                st.ring_vpn[tks[K - w:] % self.hbuf] = snap_v[K - w:K]
+                st.ring_cci[tks[K - w:] % self.hbuf] = snap_c[K - w:K]
+                st.dcum[:] = next(it)
+                st.month_vol[:] = next(it)
+                st.vpn_pref[:] = next(it)
+                st.cci_pref[:] = next(it)
+                self._state = st._replace(
+                    t=t + K, fsm=fsm, ssm_h=ssm_h, t_dev=t_dev,
+                    pred_live=(
+                        pred_block[-1].copy() if self.pred_source == "live"
+                        else st.pred_live
+                    ),
+                    metrics=ring,
+                )
+                out = {                        # (rows, K): run()'s layout
+                    "x": x.T,
+                    "state": state.T,
+                    "r_vpn": r_vpn.T,
+                    "r_cci": r_cci.T,
+                    "vpn_cost": vpn_t.T,
+                    "cci_cost": cci_t.T,
+                    "cost": np.where(x == 1, cci_t, vpn_t).T,
+                }
+        if self.obs is not None:
+            self.obs.record_chunk(
+                t,
+                [{f: v[:, k] for f, v in out.items()} for k in range(K)],
+                d_pair=d_pair, demand=d, endo=endo,
+            )
+            if drain:
+                self.obs.record_drain(t + K, fetched[-1])
+        return out
+
+    def _pack(self, st: RuntimeState, d: np.ndarray, cci_demand_block):
+        """The chunk's single flat H2D block (see :func:`_build_step_many`):
+        the (rows, K) demand [and CCI demand], then the pre-chunk window
+        reads as two (K, rows) planes."""
         t = st.t
         M, P = self.n_rows, self.n_demand_rows
-        d = np.asarray(demand_block, np.float64)
-        assert d.ndim == 2 and d.shape[0] == P, (
-            f"demand_block must be (rows, K) = ({P}, K), got {d.shape}"
-        )
         K = d.shape[1]
-        assert K >= 1, K
         endo = cci_demand_block is not None
         # Pre-chunk window reads, gathered from the HOST ring twins and
         # packed into the chunk's single H2D block (see _build_step_many —
@@ -871,80 +978,7 @@ class FleetRuntime:
             # replaces these from its snapshots, so any value works.
             block[nd + Kw * M:nd + K * M] = 0.0
             block[nd + (K + Kw) * M:] = 0.0
-        drain = False
-        if self.obs is not None:
-            cadence = self.obs.cadence
-            boundary = ((t // cadence) + 1) * cadence   # first drain > t
-            assert boundary >= t + K, (
-                f"obs drain cadence {cadence} falls mid-chunk (hour "
-                f"{boundary} inside ({t}, {t + K})): chunk ends must align "
-                f"with the drain cadence — pick K dividing the cadence, or "
-                f"step() across the boundary"
-            )
-            drain = boundary == t + K
-        fn = self._step_many_fn(endo, drain, K)
-        with jax.enable_x64():
-            fsm, ssm_h, t_dev, ring, seq, planes, drain_vec = fn(
-                self.arrays, self.policy, self._fc, st.fsm, st.ssm_h,
-                st.t_dev, st.routing, st.metrics, self._obs_edges,
-                self._hpm_dev, self._device_seq(), jax.device_put(block),
-            )
-        self._dev_seq = seq
-        it = iter(planes)                               # (K, rows) each
-        x = np.asarray(next(it)).astype(np.int64)
-        state = np.asarray(next(it)).astype(np.int64)
-        vpn_t = np.asarray(next(it))
-        cci_t = np.asarray(next(it))
-        d_pair = np.asarray(next(it))
-        if self.pred_source == "live":
-            pred_block = np.asarray(next(it))
-        r_vpn = np.asarray(next(it))
-        r_cci = np.asarray(next(it))
-        snap_v = np.asarray(next(it))
-        snap_c = np.asarray(next(it))
-
-        # Mirror the device's sequential scans into the host accumulators.
-        # ``snap[k]`` is the prefix BEFORE hour t+k (the ring-snapshot /
-        # exclusive-prefix convention); the seq carry holds the post-chunk
-        # accumulators.
-        tks = t + np.arange(K)
-        w = min(K, self.hbuf)  # K > hbuf: earlier slots would be rewritten
-        st.ring_vpn[tks[K - w:] % self.hbuf] = snap_v[K - w:K]
-        st.ring_cci[tks[K - w:] % self.hbuf] = snap_c[K - w:K]
-        dcum_d, month_vol_d, vpn_pref_d, cci_pref_d, _ = seq
-        st.vpn_pref[:] = np.asarray(vpn_pref_d)
-        st.cci_pref[:] = np.asarray(cci_pref_d)
-        st.dcum[:] = np.asarray(dcum_d)
-        st.month_vol[:] = np.asarray(month_vol_d)
-        self._state = st._replace(
-            t=t + K, fsm=fsm, ssm_h=ssm_h, t_dev=t_dev,
-            pred_live=(
-                pred_block[-1].copy() if self.pred_source == "live"
-                else st.pred_live
-            ),
-            metrics=ring,
-        )
-        out = {
-            "x": x.T,                      # (rows, K) — run()'s stacked layout
-            "state": state.T,
-            "r_vpn": r_vpn.T,
-            "r_cci": r_cci.T,
-            "vpn_cost": vpn_t.T,
-            "cci_cost": cci_t.T,
-            "cost": np.where(x == 1, cci_t, vpn_t).T,
-        }
-        if self.obs is not None:
-            self.obs.record_chunk(
-                t,
-                [{f: v[:, k] for f, v in out.items()} for k in range(K)],
-                d_pair=d_pair, demand=d, endo=endo,
-                h2d_bytes=block.nbytes,
-                d2h_bytes=sum(p.nbytes for p in planes),
-                dt_s=time.perf_counter() - t0,
-            )
-            if drain:
-                self.obs.record_drain(t + K, np.asarray(drain_vec))
-        return out
+        return block
 
     def run(self, demand, *, cci_demand=None) -> Dict[str, np.ndarray]:
         """Convenience: stream a whole (rows, T) matrix tick by tick and stack

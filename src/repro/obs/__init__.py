@@ -16,8 +16,8 @@ Quickstart::
 Design notes live in the submodules: :mod:`repro.obs.metrics` (the in-jit
 ring and why drains ride the tick's own packed transfer),
 :mod:`repro.obs.trace` (Chrome trace-event export), :mod:`repro.obs.monitors`
-(the four contracts), :mod:`repro.obs.profile` (tick latency / transfer
-accounting). Decisions are bit-identical with observability on or off —
+(the four contracts), :mod:`repro.obs.profile` (the program's spans and
+counters, and the per-call profiler that reads them). Decisions are bit-identical with observability on or off —
 the ring consumes tick outputs, it never feeds back.
 """
 from .metrics import (

@@ -32,7 +32,7 @@ from .monitors import (
     DivergenceMonitor,
     RegretMonitor,
 )
-from .profile import TickProfiler
+from .profile import TickProfiler, attach
 from .trace import TraceRecorder
 
 
@@ -123,10 +123,9 @@ class ObsReport:
             f"  cost/row/h: p50 ${q.get('p50', float('nan')):.3g}  "
             f"p95 ${q.get('p95', float('nan')):.3g}  "
             f"p99 ${q.get('p99', float('nan')):.3g}",
-            f"  ticks  : p50 {p['tick_us_p50']:.0f}µs  "
-            f"p95 {p['tick_us_p95']:.0f}µs  p99 {p['tick_us_p99']:.0f}µs  "
-            f"(h2d {mb(p['h2d_bytes'])}, d2h {mb(p['d2h_bytes'])}, "
-            f"{p['compiles']} compiles)",
+            f"  calls  : {p['calls']} — p50 {p['call_us_p50']:.0f}µs  "
+            f"p95 {p['call_us_p95']:.0f}µs  p99 {p['call_us_p99']:.0f}µs  "
+            f"(h2d {mb(p['h2d_bytes'])}, d2h {mb(p['d2h_bytes'])})",
         ]
         mons = []
         for name, s in self.monitors.items():
@@ -167,6 +166,7 @@ class FleetObserver:
             config.hist_bins, config.hist_lo, config.hist_hi
         )
         self.n_tiers = int(np.asarray(runtime.arrays.tier_bounds).shape[1])
+        attach(self)    # record the runtime's fleet.* spans
         self._init_run()
 
     def _init_run(self) -> None:
@@ -228,23 +228,20 @@ class FleetObserver:
         d_pair: np.ndarray,
         demand: np.ndarray,
         endo: bool,
-        h2d_bytes: int,
-        d2h_bytes: int,
-        dt_s: float,
     ) -> None:
-        """One ``step_many`` dispatch covering hours ``t .. t+K-1``.
+        """One ``step_many`` call covering hours ``t .. t+K-1``, made after
+        the call's ``fleet.step`` span has closed.
 
         ``outs_by_hour`` is the chunk's K per-hour step dicts, ``d_pair``
-        is (K, P) and ``demand`` (P, K). The profiler gets one per-chunk
-        record (latency amortized per hour, transfers counted once); every
-        per-hour consumer — trace, billing/regret/divergence monitors —
+        is (K, P) and ``demand`` (P, K). The profiler reads the call's span
+        and transfer bytes from the recorder; every per-hour consumer — trace, billing/regret/divergence monitors —
         sees exactly the per-tick event stream, so a chunked run's traces
         and monitor verdicts match a per-tick run's.
         """
         K = len(outs_by_hour)
         self.hours = t + K
         self.endo_seen |= endo
-        self.profiler.record_chunk(dt_s, h2d_bytes, d2h_bytes, K)
+        self.profiler.record_call()
         for k, out in enumerate(outs_by_hour):
             if self.trace is not None:
                 self.trace.observe_states(t + k, out["state"])
@@ -305,9 +302,6 @@ class FleetObserver:
             self.trace.instant(
                 t, "sync_domains", domains=int(n_domains), jobs=int(n_jobs)
             )
-
-    def note_compile(self) -> None:
-        self.profiler.note_compile()
 
     # -- checks / report ---------------------------------------------------
 

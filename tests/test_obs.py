@@ -17,6 +17,7 @@ The load-bearing pieces, each against an independent reference:
 streaming contracts in ``tests/test_fleet_runtime.py``.)
 """
 import json
+import time
 
 import numpy as np
 import pytest
@@ -33,6 +34,7 @@ from repro.fleet.plan import (
 )
 from repro.fleet.stream import FleetRuntime
 from repro.fleet.policy import fit_cost_coef
+from repro.obs import profile
 from repro.obs import (
     ContractViolation,
     DrainedMetrics,
@@ -449,10 +451,11 @@ def test_streamed_report_aggregates_match_outputs():
     )
     assert rep.lease_on_mean == pytest.approx(np.mean(out["x"].sum(axis=0)))
 
+    # run() steps one hour per call: one fleet.step span per hour.
     p = rep.profile
-    assert p["ticks"] == T and p["drains"] == 4
+    assert p["calls"] == T and p["drains"] == 4
     assert p["h2d_bytes"] > 0 and p["d2h_bytes"] > 0
-    assert p["tick_us_p50"] <= p["tick_us_p95"] <= p["tick_us_p99"]
+    assert p["call_us_p50"] <= p["call_us_p95"] <= p["call_us_p99"]
     for q in ("p50", "p95", "p99"):
         assert np.isfinite(rep.cost_quantiles[q])
 
@@ -464,20 +467,38 @@ def test_streamed_report_aggregates_match_outputs():
 
     # reset() starts a fresh observation run (fresh monitors and profile).
     rt.reset()
-    assert rt.obs.profiler.ticks == 0 and rt.obs.drained == []
+    assert rt.obs.profiler.n_calls == 0 and rt.obs.drained == []
 
 
 def test_profiler_unit():
+    """The profiler reads each call's ``fleet.step`` span and the byte
+    counters noted inside it from the recorder; a call it has read once,
+    or one the recorder did not keep, adds nothing."""
     tp = TickProfiler()
     assert np.isnan(tp.percentiles()["p50"])
-    for dt in (1e-3, 2e-3, 3e-3):
-        tp.record(dt, 100, 200)
+    t0 = time.perf_counter()
+    profile.force_recording(True)
+    try:
+        for _ in range(3):
+            with profile.span("fleet.step"):
+                profile.count("fleet.step.h2d_bytes", 100)
+                profile.count("fleet.step.d2h_bytes", 200)
+            tp.record_call()
+            tp.record_call()
+        profile.count("fleet.step.h2d_bytes", 7)   # outside every call
+        profile.force_recording(False)
+        with profile.span("fleet.step"):
+            pass
+        tp.record_call()
+    finally:
+        profile.force_recording(None)
     tp.note_drain()
-    tp.note_compile()
+    spans, _ = profile.recorded(t0)
+    dur_us = [(e - b) * 1e6 for name, b, e in spans if name == "fleet.step"]
     s = tp.summary()
-    assert s["ticks"] == 3 and s["drains"] == 1 and s["compiles"] == 1
+    assert s["calls"] == 3 == len(dur_us) and s["drains"] == 1
     assert s["h2d_bytes"] == 300 and s["d2h_bytes"] == 600
-    assert s["tick_us_p50"] == pytest.approx(2000.0)
+    assert s["call_us_p50"] == pytest.approx(float(np.median(dur_us)))
 
 
 def test_obs_requires_flag():

@@ -46,7 +46,8 @@ studies treat as exogenous.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, NamedTuple, Optional, Sequence, Union
+import math
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -243,11 +244,12 @@ def _build_step_many(
     is flattened/reset AFTER the scan — equivalent to the per-tick drain
     variant firing on the chunk's last hour, which is the only hour a
     drain cadence boundary is allowed to touch (the caller asserts the
-    alignment). Per-hour outputs come home as ``(K, rows)`` planes in the
-    per-tick ``po`` order with the window sums and prefix snapshots
-    appended, so the host can build each hour's ``step()`` dict and mirror
-    the accumulators. Chunkings are property-tested bit-exact against each
-    other in ``tests/test_fleet_runtime.py``.
+    alignment). Per-hour outputs are ``(K, rows)`` planes in the per-tick
+    ``po`` order with the window sums and prefix snapshots appended, from
+    which the host builds each hour's ``step()`` dict and mirrors the
+    accumulators; :func:`_build_step_many_packed` packs them for the trip
+    home. Chunkings are property-tested bit-exact against each other in
+    ``tests/test_fleet_runtime.py``.
     """
 
     def step_many(arrays, policy, fc, fsm, ssm_h, t, routing, ring,
@@ -449,12 +451,11 @@ def _build_step_many(
         # Ring writes are the HOST's job (its replay loop updates the numpy
         # ring twins); the device carry is the small vectors only.
         seq_out = (dcum, month_vol, vpn_pref, cci_pref, pred_live)
-        # Per-hour outputs ship home as separate (K, rows) planes riding
-        # the one result tuple, in the per-tick po order with the window
-        # sums appended. Concatenating them into a single (K, W) block
-        # would cost XLA:CPU a full extra read+write of every plane
-        # (~12MB/chunk) for zero host benefit — np.asarray of each CPU
-        # output buffer is already zero-copy.
+        # Per-hour outputs as separate (K, rows) planes, in the per-tick po
+        # order with the window sums appended. Assembling them for the trip
+        # home is the caller's job: FleetRuntime packs them into two
+        # buffers (_build_step_many_packed), the gateway masks and fetches
+        # them over its slot axis.
         planes = (ys_t[0], ys_t[1], vpn_t, cci_t, d_pair)
         if pred_source == "live":
             planes = planes + (ys_t[2],)
@@ -469,6 +470,57 @@ def _build_step_many(
         return fsm, ssm_h, t0 + K, ring, seq_out, planes, drain_vec
 
     return step_many
+
+
+def _packed_planes(pred_source: Optional[str], obs: bool) -> Tuple[str, ...]:
+    """The float64 planes of :func:`_build_step_many_packed`'s ``vals``, in
+    order: the ones the host reads on every call, then the live forecast
+    (the host adopts its last hour) and ``d_pair`` (the observer's only)."""
+    names = ("vpn_t", "cci_t", "r_vpn", "r_cci", "snap_v", "snap_c")
+    if pred_source == "live":
+        names += ("pred",)
+    if obs:
+        names += ("d_pair",)
+    return names
+
+
+def _build_step_many_packed(
+    topology: bool, pred_source: Optional[str], endo: bool,
+    obs: bool = False, drain: bool = False, K: int = 1,
+):
+    """:func:`_build_step_many` with its outputs packed for ONE trip home.
+
+    On the chip every device array is its own blocking D2H transfer, with a
+    fixed cost per array well above the cost of its bytes, so the chunk's
+    planes and accumulators come home as two buffers instead of thirteen:
+
+    * ``dec``: int8 ``(2, K, M)``, the ``x`` and FSM ``state`` planes (0/1
+      and OFF/WAITING/ON, exact in int8; the builder emits them as float64);
+    * ``vals``: one flat float64 vector, the :func:`_packed_planes` in
+      order (``(K, M)`` each, ``d_pair`` ``(K, P)``) followed by the four
+      float64 accumulators ``dcum``, ``month_vol`` (``(P,)``), ``vpn_pref``
+      and ``cci_pref`` (``(M,)``) the host mirrors.
+
+    Planes nobody reads on the host stay on the device. The device carry
+    (``seq`` included, which stays resident) and the drained metrics ring
+    are returned as the builder returns them. Concatenation and the int8
+    round trip are exact, so every value is the builder's bits.
+    """
+    step = _build_step_many(topology, pred_source, endo, obs, drain, K)
+    names = _packed_planes(pred_source, obs)
+
+    built = ("x", "state", "vpn_t", "cci_t", "d_pair") + (
+        ("pred",) if pred_source == "live" else ()
+    ) + ("r_vpn", "r_cci", "snap_v", "snap_c")      # the builder's order
+
+    def step_many_packed(*args):
+        fsm, ssm_h, t, ring, seq, planes, drain_vec = step(*args)
+        p = dict(zip(built, planes))
+        dec = jnp.stack([p["x"], p["state"]]).astype(jnp.int8)
+        vals = jnp.concatenate([p[n].ravel() for n in names] + list(seq[:4]))
+        return fsm, ssm_h, t, ring, seq, dec, vals, drain_vec
+
+    return step_many_packed
 
 
 @dataclasses.dataclass(frozen=True)
@@ -720,8 +772,8 @@ class FleetRuntime:
             # metrics ring (arg 7) is donated for the same reason as in the
             # per-tick variant.
             fn = _STEP_CACHE.setdefault(key, jax.jit(
-                _build_step_many(key[1], key[2], endo,
-                                 self.obs is not None, drain, K),
+                _build_step_many_packed(key[1], key[2], endo,
+                                        self.obs is not None, drain, K),
                 donate_argnums=(7, 10) if self.obs is not None else (10,),
             ))
         return fn
@@ -823,8 +875,13 @@ class FleetRuntime:
         between two ``step()`` calls. With observability on, the drain
         cadence must not fall strictly inside a chunk (pick K dividing the
         cadence, or break the stream at the boundary): drains then fire at
-        the same hours with bit-identical windows, riding the chunk's
-        packed D2H transfer.
+        the same hours with bit-identical windows, as a third D2H array.
+
+        Everything else comes home as two device buffers (see
+        :func:`_build_step_many_packed`): the int8 decision planes and one
+        flat float64 vector of the cost planes and accumulators, both
+        copies started before either is awaited, and the host cuts its
+        arrays out of them as views.
 
         The call is the ``fleet.step`` span of :mod:`repro.obs.profile`, cut
         into ``pack``, ``dispatch``, ``wait``, ``fetch`` and ``mirror``.
@@ -856,7 +913,7 @@ class FleetRuntime:
             with span("fleet.step.dispatch"):
                 fn = self._step_many_fn(endo, drain, K)
                 with jax.enable_x64():
-                    fsm, ssm_h, t_dev, ring, seq, planes, drain_vec = fn(
+                    fsm, ssm_h, t_dev, ring, seq, dec, vals, drain_vec = fn(
                         self.arrays, self.policy, self._fc, st.fsm, st.ssm_h,
                         st.t_dev, st.routing, st.metrics, self._obs_edges,
                         self._hpm_dev, self._device_seq(),
@@ -864,31 +921,34 @@ class FleetRuntime:
                     )
                 self._dev_seq = seq
             with span("fleet.step.wait"):
-                jax.block_until_ready(planes)
+                jax.block_until_ready((dec, vals))
             with span("fleet.step.fetch"):
-                # Every D2H copy of the call, one blocking copy each: the
-                # (K, rows) planes, the four float64 accumulators the host
-                # mirrors, and the drained metrics ring.
-                fetched = [np.asarray(a) for a in (*planes, *seq[:4])]
-                if drain:
-                    fetched.append(np.asarray(drain_vec))
+                # Every D2H copy of the call: the int8 decisions, the packed
+                # float64 vector and, on drain calls, the metrics ring. All
+                # are started before the first is awaited.
+                home = (dec, vals, drain_vec) if drain else (dec, vals)
+                for a in home:
+                    a.copy_to_host_async()
+                fetched = [np.asarray(a) for a in home]
                 count("fleet.step.d2h_arrays", len(fetched))
                 count("fleet.step.h2d_bytes", block.nbytes)
                 count("fleet.step.d2h_bytes", sum(a.nbytes for a in fetched))
 
             with span("fleet.step.mirror"):
-                it = iter(fetched)                          # (K, rows) planes
-                x = next(it).astype(np.int64)
-                state = next(it).astype(np.int64)
-                vpn_t = next(it)
-                cci_t = next(it)
-                d_pair = next(it)
-                if self.pred_source == "live":
-                    pred_block = next(it)
-                r_vpn = next(it)
-                r_cci = next(it)
-                snap_v = next(it)
-                snap_c = next(it)
+                M = self.n_rows
+                names = _packed_planes(self.pred_source, self.obs is not None)
+                shapes = [(K, P) if n == "d_pair" else (K, M) for n in names]
+                shapes += [(P,), (P,), (M,), (M,)]
+                views, o = [], 0
+                for s in shapes:
+                    n = math.prod(s)
+                    views.append(fetched[1][o:o + n].reshape(s))
+                    o += n
+                p = dict(zip(names, views))                 # (K, rows) planes
+                x = fetched[0][0].astype(np.int64)
+                state = fetched[0][1].astype(np.int64)
+                vpn_t, cci_t = p["vpn_t"], p["cci_t"]
+                snap_v, snap_c = p["snap_v"], p["snap_c"]
 
                 # Mirror the device's sequential scans into the host
                 # accumulators. ``snap[k]`` is the prefix BEFORE hour t+k
@@ -899,14 +959,12 @@ class FleetRuntime:
                 w = min(K, self.hbuf)
                 st.ring_vpn[tks[K - w:] % self.hbuf] = snap_v[K - w:K]
                 st.ring_cci[tks[K - w:] % self.hbuf] = snap_c[K - w:K]
-                st.dcum[:] = next(it)
-                st.month_vol[:] = next(it)
-                st.vpn_pref[:] = next(it)
-                st.cci_pref[:] = next(it)
+                (st.dcum[:], st.month_vol[:],
+                 st.vpn_pref[:], st.cci_pref[:]) = views[len(names):]
                 self._state = st._replace(
                     t=t + K, fsm=fsm, ssm_h=ssm_h, t_dev=t_dev,
                     pred_live=(
-                        pred_block[-1].copy() if self.pred_source == "live"
+                        p["pred"][-1].copy() if self.pred_source == "live"
                         else st.pred_live
                     ),
                     metrics=ring,
@@ -914,8 +972,8 @@ class FleetRuntime:
                 out = {                        # (rows, K): run()'s layout
                     "x": x.T,
                     "state": state.T,
-                    "r_vpn": r_vpn.T,
-                    "r_cci": r_cci.T,
+                    "r_vpn": p["r_vpn"].T,
+                    "r_cci": p["r_cci"].T,
                     "vpn_cost": vpn_t.T,
                     "cci_cost": cci_t.T,
                     "cost": np.where(x == 1, cci_t, vpn_t).T,
@@ -924,7 +982,7 @@ class FleetRuntime:
             self.obs.record_chunk(
                 t,
                 [{f: v[:, k] for f, v in out.items()} for k in range(K)],
-                d_pair=d_pair, demand=d, endo=endo,
+                d_pair=p["d_pair"], demand=d, endo=endo,
             )
             if drain:
                 self.obs.record_drain(t + K, fetched[-1])

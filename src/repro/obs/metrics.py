@@ -8,9 +8,9 @@ like the FSM state does: :func:`update_ring` appends this tick's gauges in
 slot ``ticks`` and bumps the transition counters as pure XLA ops on
 intermediates the tick already computed (``x_t``/``state_t``/``vpn_t``/
 ``cci_t``/``d_pair``/``month_cum``), and at drain cadence
-:func:`flatten_ring` is CONCATENATED ONTO the tick's packed float64 result —
-the drain rides the same single D2H the tick already pays, and the step
-returns a zeroed ring (:func:`reset_ring`) for the next window.
+:func:`flatten_ring` hands the whole ring home as one flat vector, fetched
+beside the tick's two packed buffers, and the step returns a zeroed ring
+(:func:`reset_ring`) for the next window.
 
 Bit-exactness contract: the ring only CONSUMES tick outputs, it never feeds
 back into pricing or the FSM — decisions with observability on and off are

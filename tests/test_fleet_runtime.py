@@ -431,6 +431,109 @@ def test_step_many_chunking_bit_exact(seed):
                     assert rep.hours == T and rep.violations == []
 
 
+def _variant_runtime(variant):
+    """(runtime, demand, cci_demand or None) for one step_many variant."""
+    from repro.obs import ObsConfig
+
+    if variant.startswith("topology"):
+        sc = build_topology_scenario(8, n_facilities=3, horizon=120, seed=4)
+        hpm = sc.topo.hours_per_month
+        with jax.enable_x64():
+            arrays = sc.topo.stack(optimize_routing(sc.topo, sc.demand),
+                                   jnp.float64)
+    else:
+        sc = build_fleet_scenario(6, horizon=120, history_hours=96, seed=4)
+        hpm = sc.fleet.hours_per_month
+        with jax.enable_x64():
+            arrays = sc.fleet.stack(jnp.float64)
+    kw = dict(hours_per_month=hpm)
+    if variant in ("obs", "topology_obs"):
+        kw["obs"] = ObsConfig(cadence=48)
+    if variant == "replay":
+        kw["policy"] = forecast_fleet_policy(
+            arrays, sc.demand, sc.history, steps=3, hours_per_month=hpm
+        )
+    if variant == "live":
+        kw["policy"], kw["forecaster"] = streaming_forecast_policy(
+            arrays, sc.history, steps=3, hours_per_month=hpm
+        )
+    cci = 0.5 * sc.demand if variant == "endo" else None
+    return FleetRuntime(arrays, **kw), sc.demand, cci
+
+
+def _bits(a):
+    return np.ascontiguousarray(a).tobytes()
+
+
+@pytest.mark.parametrize("variant", [
+    "plain", "endo", "replay", "live", "obs", "topology", "topology_obs",
+])
+def test_step_many_out_is_the_builders_planes(variant):
+    """The packed trip home changes no bit: every key of ``step_many``'s
+    ``out``, the mirrored accumulators, the live forecast, the observer's
+    ``d_pair`` and the drained ring equal what the unwrapped
+    ``_build_step_many`` returns for the same inputs, in ``out``'s dtypes
+    and shapes; every FSM state fits the int8 decision buffer."""
+    from repro.fleet import runtime
+
+    rt, demand, cci = _variant_runtime(variant)
+    obs = rt.obs is not None
+    seen = {}
+    if obs:
+        chunk, drain_ = rt.obs.record_chunk, rt.obs.record_drain
+        rt.obs.record_chunk = lambda t, outs, **kw: (
+            seen.update(d_pair=kw["d_pair"]), chunk(t, outs, **kw))
+        rt.obs.record_drain = lambda t, v: (
+            seen.update(drain=v), drain_(t, v))
+    K, M, P = 24, rt.n_rows, rt.n_demand_rows
+    assert rt.topology == variant.startswith("topology")
+    assert (rt.pred_source or "") == (
+        variant if variant in ("replay", "live") else "")
+    for t in range(0, 96, K):
+        st, d = rt._state, demand[:, t:t + K]
+        c = None if cci is None else cci[:, t:t + K]
+        drain = obs and (t + K) % rt.obs.cadence == 0
+        ref_fn = jax.jit(runtime._build_step_many(
+            rt.topology, rt.pred_source, c is not None, obs, drain, K))
+        with jax.enable_x64():
+            ref = ref_fn(rt.arrays, rt.policy, rt._fc, st.fsm, st.ssm_h,
+                         st.t_dev, st.routing, st.metrics, rt._obs_edges,
+                         rt._hpm_dev, rt._device_seq(),
+                         jax.device_put(rt._pack(st, d, c)))
+            _, _, _, _, seq, planes, drain_vec = jax.tree.map(np.asarray, ref)
+        x, state, vpn_t, cci_t, d_pair, *rest = planes
+        pred = rest.pop(0) if rt.pred_source == "live" else None
+        r_vpn, r_cci, snap_v, snap_c = rest
+        assert np.array_equal(state.astype(np.int8), state)
+        assert set(np.unique(state)) <= {0, 1, 2}
+
+        seen.clear()
+        out = rt.step_many(d, cci_demand_block=c)
+        want = {
+            "x": x.astype(np.int64).T, "state": state.astype(np.int64).T,
+            "r_vpn": r_vpn.T, "r_cci": r_cci.T,
+            "vpn_cost": vpn_t.T, "cci_cost": cci_t.T,
+            "cost": np.where(x == 1, cci_t, vpn_t).T,
+        }
+        assert out.keys() == want.keys()
+        for k, v in want.items():
+            assert out[k].dtype == v.dtype and out[k].shape == (M, K), k
+            assert _bits(out[k]) == _bits(v), (variant, t, k)
+        got = rt._state
+        for k, v in zip(("dcum", "month_vol", "vpn_pref", "cci_pref"), seq):
+            assert _bits(getattr(got, k)) == _bits(v), (variant, t, k)
+        assert _bits(got.ring_vpn[(t + K - 1) % rt.hbuf]) == _bits(snap_v[-1])
+        assert _bits(got.ring_cci[(t + K - 1) % rt.hbuf]) == _bits(snap_c[-1])
+        if pred is not None:
+            assert _bits(got.pred_live) == _bits(pred[-1])
+        if obs:
+            assert seen["d_pair"].shape == (K, P)
+            assert _bits(seen["d_pair"]) == _bits(d_pair)
+            assert ("drain" in seen) == drain
+            if drain:
+                assert _bits(seen["drain"]) == _bits(drain_vec)
+
+
 def test_replay_single_segment_is_plan_topology():
     """A one-entry schedule must reproduce plan_topology bit-for-bit (the
     replay oracle degenerates to the offline planner)."""

@@ -72,18 +72,34 @@ def _live_runtime(sc):
                         hours_per_month=sc.fleet.hours_per_month)
 
 
-@pytest.mark.parametrize("live, planes", [(False, 9), (True, 10)])
-def test_step_counts_every_transfer(sc, recording, live, planes):
-    """Nine (K, rows) float64 planes (ten with a live forecaster) and four
-    (rows,) accumulators come home; the H2D block is the demand and two
-    window-read planes."""
-    rt = _live_runtime(sc) if live else FleetRuntime(sc.fleet)
+@pytest.mark.parametrize("live, obs, planes",
+                         [(False, False, 6), (True, False, 7), (False, True, 7)])
+def test_step_counts_every_transfer(sc, recording, live, obs, planes):
+    """Two packed buffers come home: the int8 ``x``/``state`` planes and one
+    float64 vector of six (K, rows) planes (seven with a live forecaster's
+    prediction or the observer's ``d_pair``) and four (rows,) accumulators;
+    a drain call brings the metrics ring as a third. The H2D block is the
+    demand and two window-read planes."""
+    from repro.obs import ObsConfig
+
+    if live:
+        rt = _live_runtime(sc)
+    else:
+        rt = FleetRuntime(sc.fleet, obs=ObsConfig(cadence=K) if obs else None)
+    drained = []
+    if obs:
+        record_drain = rt.obs.record_drain
+        rt.obs.record_drain = lambda t, v: (drained.append(v), record_drain(t, v))
     t0 = time.perf_counter()
     rt.step_many(sc.demand[:, :K])
     _, counts = profile.recorded(t0)
     got = {name: n for name, _, n in counts}
-    assert got["fleet.step.d2h_arrays"] == planes + 4
-    assert got["fleet.step.d2h_bytes"] == 8 * N_LINKS * (planes * K + 4)
+    assert len(drained) == int(obs)
+    assert got["fleet.step.d2h_arrays"] == 2 + len(drained)
+    assert got["fleet.step.d2h_bytes"] == (
+        2 * K * N_LINKS + 8 * N_LINKS * (planes * K + 4)
+        + sum(v.nbytes for v in drained)
+    )
     assert got["fleet.step.h2d_bytes"] == 8 * N_LINKS * 3 * K
 
 

@@ -8,8 +8,9 @@ readings it gives are the upper ends the limits are set below (``PERF.md``).
 
     python3 bench/control.py --workload fleet2048.stream_k24 --seed 7 --seconds 20
 
-runs one cell with the control in the program's place and prints the
-numbers compared beside their limits. It needs no accelerator. The
+runs one cell with its kind's control (``CONTROL`` of ``bench/kinds/<kind>.py``;
+for a ``fleet``, this module) in the program's place and prints the numbers
+compared beside their limits. It needs no accelerator. The
 benchmark's own runs never run it; ``bench/tests/test_control.py`` runs it
 at a small size.
 """
@@ -89,8 +90,10 @@ def main(argv=None) -> int:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     from bench.harness import Registry, report_checks, run_cell
 
-    result = run_cell(Registry(root), args.workload, args.seed, args.seconds, False,
-                      t_start=t_start, program=sys.modules[__name__],
+    registry = Registry(root)
+    program = registry.kind(registry.cell(args.workload)).CONTROL
+    result = run_cell(registry, args.workload, args.seed, args.seconds, False,
+                      t_start=t_start, program=program,
                       require_accelerator=False, compile_cache=False)
     report_checks(result, out=sys.stdout)
     return 0

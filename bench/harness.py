@@ -3,7 +3,11 @@
 Everything that belongs to one configuration, traffic mix or metric is found
 by its name in ``BENCHMARK.json``:
 
-* a configuration's sizes in the file its entry names (``bench/configs/``);
+* a configuration's sizes in the file its entry names (``bench/configs/``),
+  and its kind in ``bench/kinds/<kind>.py``, by the file's ``"kind"`` key
+  (``fleet`` where it names none). The kind builds the scenario from the seed,
+  computes the reference the checks take, and names the program's entry
+  points (``SUT``) and their lower-precision control (``CONTROL``);
 * a traffic mix in ``bench/traffic/<name>.json``, whose ``driver`` names the
   loop that drives the program (``bench/drivers/<driver>.py``) and whose
   other keys are that loop's parameters. A driver's ``call()`` is one timed
@@ -12,8 +16,8 @@ by its name in ``BENCHMARK.json``:
 * a metric's reader in ``bench/metrics/<name>.py``: ``read(run)`` returns the
   number, or ``None`` where the run holds nothing to read.
 
-So a later change adds a configuration, a mix, a driver or a metric by adding
-files and entries, never by editing one that is there.
+So a later change adds a configuration of a new kind, a mix, a driver or a
+metric by adding files and entries, never by editing one that is there.
 """
 from __future__ import annotations
 
@@ -29,7 +33,7 @@ import tempfile
 import time
 from typing import Dict, List, Optional
 
-from bench import reference, scenario, tracing
+from bench import tracing
 from bench.compile_log import CompileLog, use_compile_cache
 
 
@@ -67,6 +71,16 @@ class Registry:
     def config(self, cell: dict) -> dict:
         entry = next(c for c in self.bench["configs"] if c["name"] == cell["config"])
         return _load_json(os.path.join(self.root, entry["file"]))
+
+    def kind(self, cell: dict):
+        """The module of the kind of the cell's configuration."""
+        return self.kind_of(self.config(cell))
+
+    def kind_of(self, config: dict):
+        """The module of a configuration's kind; one that names none is a fleet."""
+        name = config.get("kind", "fleet")
+        return _load_module(os.path.join(self.root, "bench", "kinds", name + ".py"),
+                            f"bench_kind_{name}")
 
     def traffic(self, cell: dict) -> dict:
         return _load_json(os.path.join(self.root, "bench", "traffic",
@@ -118,19 +132,20 @@ def run_cell(registry: Registry, workload: str, seed: int, seconds: float,
     cell = registry.cell(workload)
     config, traffic = registry.config(cell), registry.traffic(cell)
     driver_mod = registry.driver(traffic)
+    kind = registry.kind(cell)
     wanted = registry.metrics(workload, trace)
     devs = _devices(int(cell["chips"]), require_accelerator)
     import jax
 
     if program is None:
-        from bench import sut as program
+        program = kind.SUT
     if compile_cache:
         use_compile_cache(registry.root)
     log = CompileLog()
 
     span = jax.profiler.TraceAnnotation if trace else (lambda name: contextlib.nullcontext())
-    fleet = scenario.build(config, seed)
-    drv = driver_mod.Driver(fleet, config, traffic, seed, span, program)
+    built = kind.build(config, seed)
+    drv = driver_mod.Driver(built, config, traffic, seed, span, program)
     drv.setup()
     compile_s, n_setup, hits = log.snapshot()
     trace_dir = None
@@ -173,8 +188,7 @@ def run_cell(registry: Registry, workload: str, seed: int, seconds: float,
             shutil.rmtree(trace_dir, ignore_errors=True)
 
     t_ref = time.perf_counter()
-    a = scenario.LinkArrays(fleet.links, fleet.hours_per_month)
-    ref = reference.run(a, fleet.demand[:, :drv.hours_needed()])
+    ref = kind.reference(built, drv.hours_needed())
     checks, bad_calls = drv.checks(ref)
     print(f"bench: {workload} seed={seed} setup_s={setup_s:.3f} compiles={n_setup} "
           f"cache_hits={hits} calls={len(calls)} window_s={run.window_s:.3f} keep_s={keep_s:.4f} "

@@ -1,10 +1,10 @@
 """The harness end to end on the CPU, at a small size.
 
-Each cell's run passes the end of its demand year inside the window (the
-streams wrap it with ``reset()``; a plan call decides all of it), and still
-equals the reference; the result
-line holds the contract's keys; a run without an accelerator prints
-nothing and fails.
+Every cell of ``BENCHMARK.json`` runs. Each cell's run passes the end of its
+demand year inside the window (the streams wrap it with ``reset()``; a plan
+call decides all of it), and still equals the reference; the result line
+holds the contract's keys; a run without an accelerator prints nothing and
+fails.
 """
 from __future__ import annotations
 
@@ -16,9 +16,10 @@ import sys
 
 import pytest
 
-from bench.tests.conftest import REPO, TINY, run_tiny
+from bench.harness import Registry
+from bench.tests.conftest import REPO, SEED, run_tiny
 
-CELLS = ["fleet2048.stream_k24", "fleet2048.plan", "fleet2048.stream_k1"]
+CELLS = [w["name"] for w in Registry(REPO).bench["workloads"]]
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
 
 
@@ -35,8 +36,12 @@ def test_cell_equals_reference_past_the_horizon(tiny_root, workload):
               if workload in m.get("workloads", [workload])}
     assert set(r["metrics"]) == wanted
     assert all(set(m) == {"value", "unit"} and m["value"] > 0 for m in r["metrics"].values())
-    hours = r["metrics"]["row_hours_per_s"]["value"] * 1.5 / TINY["n_links"]
-    assert hours > TINY["horizon"], "the window never passed the end of the demand year"
+    # The rows and hours of the year the kind builds at its tiny size.
+    registry = Registry(tiny_root)
+    cell = registry.cell(workload)
+    rows, year = registry.kind(cell).build(registry.config(cell), SEED).demand.shape
+    hours = r["metrics"]["row_hours_per_s"]["value"] * 1.5 / rows
+    assert hours > year, "the window never passed the end of the demand year"
 
 
 def _run_py(root, *args, **env):

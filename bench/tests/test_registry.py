@@ -1,12 +1,18 @@
-"""A configuration, a traffic mix and a metric added as new files, with new
-``BENCHMARK.json`` entries and no existing file edited, are found by name."""
+"""A configuration, a traffic mix, a metric and a kind added as new files,
+with new ``BENCHMARK.json`` entries and no existing file edited, are found by
+name."""
 from __future__ import annotations
 
 import filecmp
 import json
 import os
+import time
 
-from bench.tests.conftest import REPO, run_tiny
+import numpy as np
+
+from bench import scenario
+from bench.harness import Registry, run_cell
+from bench.tests.conftest import REPO, SEED, run_tiny
 
 
 def test_new_config_mix_and_metric_need_only_new_files(tiny_root):
@@ -39,6 +45,89 @@ def test_new_config_mix_and_metric_need_only_new_files(tiny_root):
     assert r["correct"], r["checks"]
     assert r["metrics"]["calls_in_window"]["value"] == r["attempted"]
     assert r["metrics"]["row_hours_per_s"]["value"] > 0
+    # Every file that was there before is as it was in the repository.
+    for rel in before - {"BENCHMARK.json"}:
+        if not rel.startswith("bench/configs/"):
+            assert filecmp.cmp(os.path.join(tiny_root, rel), os.path.join(REPO, rel), shallow=False), rel
+
+
+STUB_KIND = '''
+import numpy as np
+
+from bench import control, reference as _reference, scenario, sut
+
+SUT, CONTROL = sut, control
+TINY = {"n_links": 8, "horizon": 480, "hours_per_month": 120}
+FAULTS = ("plan_program",)
+
+
+def build(config, seed):
+    fleet = scenario.build(config, seed)
+    order = np.random.default_rng(seed % 2**64).permutation(len(fleet.links))
+    return scenario.Fleet(tuple(fleet.links[i] for i in order), fleet.demand[order],
+                          fleet.hours_per_month)
+
+
+def reference(built, hours):
+    return _reference.run(scenario.LinkArrays(built.links, built.hours_per_month),
+                          built.demand[:, :hours])
+'''
+
+
+class _Spy(Registry):
+    """Counts the harness's calls of the kind it is handed."""
+
+    def kind(self, cell):
+        kind = super().kind(cell)
+        self.used = {"file": kind.__file__, "build": 0, "reference": 0}
+        build, reference = kind.build, kind.reference
+
+        def counted_build(*a):
+            self.used["build"] += 1
+            return build(*a)
+
+        def counted_reference(*a):
+            self.used["reference"] += 1
+            return reference(*a)
+
+        kind.build, kind.reference = counted_build, counted_reference
+        return kind
+
+
+def test_new_kind_needs_only_new_files(tiny_root):
+    before = {os.path.relpath(os.path.join(d, f), tiny_root)
+              for d, _, fs in os.walk(tiny_root) for f in fs}
+    stub = os.path.join(tiny_root, "bench", "kinds", "shuffled.py")
+    with open(stub, "w") as f:
+        f.write(STUB_KIND)
+    with open(os.path.join(tiny_root, "bench", "configs", "fleet2048.json")) as f:
+        cfg = json.load(f)
+    cfg.update(name="shuffled8", kind="shuffled")
+    with open(os.path.join(tiny_root, "bench", "configs", "shuffled8.json"), "w") as f:
+        json.dump(cfg, f)
+    path = os.path.join(tiny_root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "shuffled8", "source": "test",
+                             "file": "bench/configs/shuffled8.json", "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "shuffled8.plan", "config": "shuffled8",
+                               "traffic": "plan", "chips": 1, "why": "test"})
+    with open(path, "w") as f:
+        json.dump(bench, f)
+
+    spy = _Spy(tiny_root)
+    r = run_cell(spy, "shuffled8.plan", SEED, 0.5, False, t_start=time.perf_counter(),
+                 require_accelerator=False, compile_cache=False)
+    assert r["correct"], r["checks"]
+    assert spy.used == {"file": stub, "build": 1, "reference": 1}
+    registry = Registry(tiny_root)
+    kind = registry.kind(registry.cell("shuffled8.plan"))
+    rows, fleet_rows = kind.build(cfg, SEED).demand, scenario.build(cfg, SEED).demand
+    assert not np.array_equal(rows, fleet_rows), "the stub kind built the fleet's own order"
+    assert sorted(map(tuple, rows)) == sorted(map(tuple, fleet_rows))
+
+    r = run_tiny(tiny_root, "shuffled8.plan", seconds=0.5, program=kind.CONTROL)
+    assert not r["correct"], r["checks"]
     # Every file that was there before is as it was in the repository.
     for rel in before - {"BENCHMARK.json"}:
         if not rel.startswith("bench/configs/"):

@@ -14,10 +14,15 @@ by its name in ``BENCHMARK.json``:
   call of the entry point, ``keep()`` files its outputs for the comparison
   after the window, outside the call's time;
 * a metric's reader in ``bench/metrics/<name>.py``: ``read(run)`` returns the
-  number, or ``None`` where the run holds nothing to read.
+  number, or ``None`` where the run holds nothing to read. A metric with no
+  ``workloads`` list is read in every cell;
+* a fault planter in ``bench/faults/<name>.py``, by the names in a kind's
+  ``FAULTS``: ``plant(monkeypatch, fault)`` breaks that timed program for the
+  tests (``bench/tests/test_faults.py``).
 
-So a later change adds a configuration of a new kind, a mix, a driver or a
-metric by adding files and entries, never by editing one that is there.
+So a later change adds a configuration of a new kind, a mix, a driver, a
+metric or a planter by adding files and entries, never by editing one that
+is there.
 """
 from __future__ import annotations
 
@@ -99,6 +104,11 @@ class Registry:
     def reader(self, metric: str):
         return _load_module(os.path.join(self.root, "bench", "metrics", metric + ".py"),
                             f"bench_metric_{metric}")
+
+    def planter(self, name: str):
+        """The module that plants faults in the timed program ``name``."""
+        return _load_module(os.path.join(self.root, "bench", "faults", name + ".py"),
+                            f"bench_fault_{name}")
 
 
 @dataclasses.dataclass

@@ -1,7 +1,9 @@
-"""The ``fleet`` kind gives, bit for bit, the scenario and reference the
-harness built before kinds: ``bench.scenario.build`` and
-``bench.reference.run`` over ``LinkArrays``. So no fleet cell's yardstick
-moved when the harness began to reach them through the kind."""
+"""Every cell's configuration leads to a kind module that holds what the
+harness needs, whatever kinds ``BENCHMARK.json`` mixes; and the ``fleet``
+kind gives, bit for bit, the scenario and reference the harness built before
+kinds: ``bench.scenario.build`` and ``bench.reference.run`` over
+``LinkArrays``. So no fleet cell's yardstick moved when the harness began to
+reach them through the kind."""
 from __future__ import annotations
 
 import json
@@ -28,11 +30,28 @@ def tiny_config(fleet_kind):
     return cfg
 
 
-def test_a_config_without_kind_is_a_fleet(fleet_kind):
-    registry = Registry(REPO)
+KIND_NAMES = ("build", "reference", "SUT", "CONTROL", "TINY", "FAULTS")
+
+
+def check_kinds(registry: Registry) -> None:
+    """What holds of every cell, whatever the mix of kinds: a configuration
+    that names no kind loads ``bench/kinds/fleet.py``, one that names a kind
+    loads ``bench/kinds/<kind>.py``, the module holds every name the harness
+    and the tests take of a kind, and each of its ``FAULTS`` has a planter."""
     for cell in registry.bench["workloads"]:
-        assert "kind" not in registry.config(cell)
-        assert registry.kind(cell).__file__ == fleet_kind.__file__
+        name = registry.config(cell).get("kind", "fleet")
+        kind = registry.kind(cell)
+        assert kind.__file__ == os.path.join(registry.root, "bench", "kinds", name + ".py"), cell
+        missing = [k for k in KIND_NAMES if not hasattr(kind, k)]
+        assert not missing, f"kind {name!r} lacks {missing}"
+        assert kind.FAULTS, f"kind {name!r} names no timed program to plant faults in"
+        for family in kind.FAULTS:
+            assert callable(registry.planter(family).plant), family
+
+
+def test_a_config_without_kind_is_a_fleet(fleet_kind):
+    check_kinds(Registry(REPO))
+    assert fleet_kind.__file__ == os.path.join(REPO, "bench", "kinds", "fleet.py")
     assert (fleet_kind.SUT, fleet_kind.CONTROL) == (sut, control)
 
 

@@ -132,3 +132,58 @@ def test_new_kind_needs_only_new_files(tiny_root):
     for rel in before - {"BENCHMARK.json"}:
         if not rel.startswith("bench/configs/"):
             assert filecmp.cmp(os.path.join(tiny_root, rel), os.path.join(REPO, rel), shallow=False), rel
+
+
+PLANTER = '''
+PLANTED = []
+
+
+def plant(monkeypatch, fault):
+    PLANTED.append(fault)
+'''
+
+
+def test_a_cell_of_a_new_kind_in_the_real_benchmark_needs_only_new_files(tiny_root):
+    """The guard: a stub kind, its planter, configuration and cell added as new
+    files and new entries to a copy of the repository's own ``BENCHMARK.json``
+    keep every kind invariant, get the per-layer metrics that name no cells,
+    and find their planter by name."""
+    from bench.tests.test_kinds import check_kinds
+
+    before = {os.path.relpath(os.path.join(d, f), tiny_root)
+              for d, _, fs in os.walk(tiny_root) for f in fs}
+    with open(os.path.join(tiny_root, "bench", "kinds", "stubbed.py"), "w") as f:
+        f.write(STUB_KIND.replace('FAULTS = ("plan_program",)', 'FAULTS = ("stub_program",)'))
+    with open(os.path.join(tiny_root, "bench", "faults", "stub_program.py"), "w") as f:
+        f.write(PLANTER)
+    with open(os.path.join(tiny_root, "bench", "configs", "fleet2048.json")) as f:
+        cfg = json.load(f)
+    cfg.update(name="stubbed8", kind="stubbed")
+    with open(os.path.join(tiny_root, "bench", "configs", "stubbed8.json"), "w") as f:
+        json.dump(cfg, f)
+    path = os.path.join(tiny_root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        assert bench == json.load(f), "the copy is not the repository's own benchmark"
+    bench["configs"].append({"name": "stubbed8", "source": "test",
+                             "file": "bench/configs/stubbed8.json", "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "stubbed8.plan", "config": "stubbed8",
+                               "traffic": "plan", "chips": 1, "why": "test"})
+    with open(path, "w") as f:
+        json.dump(bench, f)
+
+    registry = Registry(tiny_root)
+    check_kinds(registry)
+    kind = registry.kind(registry.cell("stubbed8.plan"))
+    assert kind.__file__ == os.path.join(tiny_root, "bench", "kinds", "stubbed.py")
+    traced = {m["name"] for m in registry.metrics("stubbed8.plan", trace=True)}
+    assert {"device_idle_share", "device_ns_per_row_hour", "compiles_in_window",
+            "compile_s"} <= traced
+    planter = registry.planter(kind.FAULTS[0])
+    assert planter.__file__ == os.path.join(tiny_root, "bench", "faults", "stub_program.py")
+    planter.plant(None, "answer_altered")
+    assert planter.PLANTED == ["answer_altered"]
+    for rel in before - {"BENCHMARK.json"}:
+        if not rel.startswith("bench/configs/"):
+            assert filecmp.cmp(os.path.join(tiny_root, rel), os.path.join(REPO, rel), shallow=False), rel

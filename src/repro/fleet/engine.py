@@ -53,7 +53,7 @@ from repro.core.costmodel import (
 )
 from repro.core.togglecci import run_togglecci
 from repro.kernels.tiered_cost import tiered_cost_batched
-from repro.obs.profile import span
+from repro.obs.profile import count, span
 
 from .policy import make_policy, policy_scan
 from .routing import RoutingOperand, RoutingPlan, as_routing_plan
@@ -73,9 +73,15 @@ def _run_policies(policy, demand_rows, vpn, cci):
 
 
 def _plan_outputs(policy, d, vpn, cci) -> Dict[str, jax.Array]:
-    """Shared tail of both planners: run the policies, add the static
+    """Shared tail of every offline plan: run the policies, add the static
     comparators. ALWAYS-CCI still pays the provisioning delay: the first D
-    hours ride VPN (paper Fig. 11's "misses the first D")."""
+    hours ride VPN (paper Fig. 11's "misses the first D").
+
+    The decision planes ``x`` (0/1) and ``state`` (OFF/WAITING/ON) leave as
+    int8, which holds them exactly: the caller's copy home is a sixth of the
+    scan's int64/int32 planes. ``toggle_cost`` is summed inside the scan
+    from the wide ``x``, before the cast.
+    """
     out = _run_policies(policy, d, vpn, cci)
     T = d.shape[1]
     with jax.named_scope("comparators"):
@@ -83,9 +89,9 @@ def _plan_outputs(policy, d, vpn, cci) -> Dict[str, jax.Array]:
         static_cci = jnp.sum(jnp.where(cci_live, cci, vpn), axis=1)
         static_vpn = jnp.sum(vpn, axis=1)
     return {
-        "x": out["x"],                     # (rows, T) 0/1 decision sequences
-        "state": out["state"],             # (rows, T) FSM states
-        "toggle_cost": out["total_cost"],  # (rows,)
+        "x": out["x"].astype(jnp.int8),          # (rows, T) int8 0/1 decisions
+        "state": out["state"].astype(jnp.int8),  # (rows, T) int8 FSM states
+        "toggle_cost": out["total_cost"],        # (rows,)
         "static_vpn": static_vpn,
         "static_cci": static_cci,
         "vpn_hourly": vpn,
@@ -270,11 +276,13 @@ def plan_fleet(
         paper's ToggleCCI, bit-for-bit the pre-policy-layer behavior).
       hours_per_month: billing calendar (taken from the spec when given).
     Returns:
-      dict of per-link arrays — see ``_build_plan_fn`` (plus ``demand``, an
-      alias of ``pair_demand`` kept for the per-link view).
+      dict of per-link arrays — see ``_build_plan_fn`` and ``_plan_outputs``
+      (``x`` and ``state`` int8) — plus ``demand``, an alias of
+      ``pair_demand`` kept for the per-link view.
     """
     # Host work only: the plan runs on after the return, and the caller's
-    # fetch of its outputs lies outside the ``fleet.plan`` span.
+    # fetch of its outputs lies outside the ``fleet.plan`` span. The
+    # counter reads shapes and dtypes, so it does not wait for the device.
     with span("fleet.plan"), jax.enable_x64():
         kind = "reactive"
         if isinstance(fleet, FleetSpec):
@@ -293,6 +301,9 @@ def plan_fleet(
                 arrays, demand, policy, hours_per_month, use_pallas
             ))
         out["demand"] = out["pair_demand"]
+        count("fleet.plan.out_bytes", sum(
+            out[k].nbytes for k in ("x", "state", "toggle_cost")
+        ))
         return out
 
 
@@ -349,7 +360,8 @@ def plan_topology(
         arrays). ``None`` resolves the spec's ``policy`` kind (default
         reactive — bit-for-bit the pre-policy-layer behavior).
     Returns:
-      dict of per-port arrays — see ``_build_plan_fn``.
+      dict of per-port arrays — see ``_build_plan_fn`` and
+      ``_plan_outputs`` (``x`` and ``state`` int8).
     """
     with jax.enable_x64():
         kind = "reactive"
@@ -397,7 +409,8 @@ def replay_plan_topology(
     float64 prefixes, and the FSM carry rides across the swap uninterrupted.
 
     A single-segment schedule ``[(0, routing)]`` reproduces
-    :func:`plan_topology` on that routing bit-for-bit.
+    :func:`plan_topology` on that routing bit-for-bit; the outputs are
+    ``_plan_outputs``'s (``x`` and ``state`` int8).
     """
     assert isinstance(arrays, TopologyArrays), (
         "replay_plan_topology replays shared-port routings; fleet mode has "

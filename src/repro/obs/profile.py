@@ -15,8 +15,10 @@ Program spans are named ``fleet.*``:
   ``fleet.step.d2h_bytes`` once per call;
 * ``fleet.plan`` — the host part of :func:`repro.fleet.engine.plan_fleet`
   (spec stacking, policy resolution, the jitted call's enqueue), with
-  children ``fleet.plan.policy`` and ``fleet.plan.dispatch``. The caller's
-  fetch of the plan's outputs lies outside it.
+  children ``fleet.plan.policy`` and ``fleet.plan.dispatch``, and counter
+  ``fleet.plan.out_bytes`` (the bytes of ``x``, ``state`` and
+  ``toggle_cost`` the call returns) once per call. The caller's fetch of
+  the plan's outputs lies outside it.
 
 Every span is a ``jax.profiler.TraceAnnotation`` while a profiler trace is
 active, so it shows in Perfetto or TensorBoard on the same clock as the

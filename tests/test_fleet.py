@@ -1,10 +1,14 @@
 """Fleet subsystem tests: SoA stacking, batched-vs-sequential equivalence,
-capacity ceilings, tier-table padding, and the report layer."""
+capacity ceilings, tier-table padding, the report layer, and the offline
+plans' int8 decision planes."""
+import time
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.costmodel import tiered_marginal_cost_tables
@@ -16,13 +20,21 @@ from repro.fleet.plan import (
     LinkSpec,
     build_fleet_scenario,
     build_report,
+    build_topology_scenario,
     fleet_from_params,
     link_capacity_gb_hr,
+    make_policy,
+    optimize_routing,
     plan_fleet,
     plan_fleet_reference,
+    plan_topology,
+    plan_topology_reference,
+    replay_plan_topology,
     toggle_events,
 )
+from repro.fleet.engine import _run_policies
 from repro.fleet.spec import PAD_BOUND
+from repro.obs import profile
 
 HORIZON = 1600
 
@@ -215,3 +227,83 @@ def test_report_aggregates_and_oracle_bound():
     assert "oracle" in t
     text = rep.render_text()
     assert "fleet total" in text and rep.links[0].name in text
+
+
+# ---------------------------------------------------------------------------
+# Offline plans return their decision planes as int8
+# ---------------------------------------------------------------------------
+
+
+def _offline_plan(entry):
+    """(plan, reference, policy, demand of the rows) of one offline entry
+    point on a small case; the topology references run the FSM on the
+    plan's own port series."""
+    if entry == "plan_fleet":
+        sc = build_fleet_scenario(6, horizon=600, seed=3)
+        plan = plan_fleet(sc.fleet, sc.demand)
+        with jax.enable_x64():
+            policy = make_policy("reactive", sc.fleet.stack(jnp.float64).toggle)
+        ref = plan_fleet_reference(sc.fleet, sc.demand)
+        return plan, ref, policy, plan["demand"]
+    sc = build_topology_scenario(8, n_facilities=2, horizon=600, seed=5)
+    routing = optimize_routing(sc.topo, sc.demand)
+    with jax.enable_x64():
+        arrays = sc.topo.stack(routing, jnp.float64)
+        policy = make_policy("reactive", arrays.toggle)
+    plan = plan_topology(sc.topo, sc.demand, routing=routing)
+    rows = plan["port_demand"]
+    if entry == "replay_plan_topology":       # one segment: plan_topology's series
+        plan = replay_plan_topology(
+            arrays, sc.demand, [(0, arrays.routing)],
+            hours_per_month=sc.topo.hours_per_month,
+        )
+    series = {"vpn": np.asarray(plan["vpn_hourly"]),
+              "cci": np.asarray(plan["cci_hourly"])}
+    ref = plan_topology_reference(sc.topo, sc.demand, routing, port_costs=series)
+    return plan, ref, policy, rows
+
+
+OFFLINE_ENTRIES = ("plan_fleet", "plan_topology", "replay_plan_topology")
+
+
+@pytest.mark.parametrize("entry", OFFLINE_ENTRIES)
+def test_offline_plan_decisions_are_int8_and_equal_the_reference(entry):
+    plan, ref, _, _ = _offline_plan(entry)
+    for k in ("x", "state"):
+        got = np.asarray(plan[k])
+        assert got.dtype == np.int8, k
+        assert np.array_equal(got, ref[k]), k
+    assert 0 < np.asarray(plan["x"]).mean() < 1, "no row toggled"
+
+
+@pytest.mark.parametrize("entry", OFFLINE_ENTRIES)
+def test_offline_plan_toggle_cost_is_the_wide_scans_bits(entry):
+    """``toggle_cost`` is summed from the scan's wide ``x`` before the int8
+    cast: bit for bit what the policy scan alone gives on the plan's own
+    series, whose planes hold the same values in wider integers."""
+    plan, _, policy, rows = _offline_plan(entry)
+    with jax.enable_x64():
+        wide = jax.jit(_run_policies)(
+            policy, rows, plan["vpn_hourly"], plan["cci_hourly"]
+        )
+    assert np.dtype(wide["x"].dtype).itemsize > 1
+    assert np.array_equal(np.asarray(wide["x"]), np.asarray(plan["x"]))
+    assert np.array_equal(np.asarray(wide["state"]), np.asarray(plan["state"]))
+    got = np.asarray(plan["toggle_cost"])
+    assert got.dtype == np.float64
+    assert got.tobytes() == np.asarray(wide["total_cost"]).tobytes()
+
+
+def test_plan_fleet_notes_the_bytes_it_returns_once_per_call():
+    n, hours = 5, 300
+    sc = build_fleet_scenario(n, horizon=hours, seed=4)
+    profile.force_recording(True)
+    try:
+        t0 = time.perf_counter()
+        for _ in range(2):
+            plan_fleet(sc.fleet, sc.demand)
+        _, counts = profile.recorded(t0)
+    finally:
+        profile.force_recording(None)
+    got = [c for name, _, c in counts if name == "fleet.plan.out_bytes"]
+    assert got == [2 * n * hours + 8 * n] * 2     # int8 x and state, float64 cost

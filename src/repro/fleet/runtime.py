@@ -226,17 +226,12 @@ def _build_step_many(
     The (M, hbuf) prefix window rings never touch the device AT ALL: any
     formulation that keeps them in the jitted fn pays ring-sized memory
     traffic per chunk (a carried dynamic-update-slice copies the whole
-    ring every inner step, ~26 ms/chunk at 2048x337 f64; even a hoisted
-    post-scan ``.at[:, slots].set`` scatter lowers on CPU to a K-step
-    while loop entered through a full-ring copy — measured ~4 ms/chunk).
-    The host already maintains numpy ring twins in its replay loop, so
-    the caller GATHERS the pre-chunk window reads from them up front and
-    packs the two (rows, K) planes into the chunk's single H2D block;
-    in-chunk reads — hour t+k reading a slot this same chunk writes,
-    i.e. rows with window h < K — come from the prefix-scan snapshot
-    planes instead. Same f64 values either way (host and device prefixes
-    are bit-identical twins), and the device only ever touches (rows, K)
-    planes.
+    ring every inner step, ~26 ms/chunk at 2048x337 f64; a hoisted
+    scatter ~4 ms/chunk on CPU). The caller gathers the pre-chunk window
+    reads from its numpy ring twins (:func:`_ring_reads`) into the
+    chunk's single H2D block; in-chunk reads (rows with window h < K)
+    come from the prefix-scan snapshot planes instead. Same f64 values
+    either way: host and device prefixes are bit-identical twins.
 
     ``hpm`` (the billing calendar) rides as a traced int operand so
     calendars don't multiply compiled variants; ``K`` is static (one
@@ -244,12 +239,11 @@ def _build_step_many(
     is flattened/reset AFTER the scan — equivalent to the per-tick drain
     variant firing on the chunk's last hour, which is the only hour a
     drain cadence boundary is allowed to touch (the caller asserts the
-    alignment). Per-hour outputs are ``(K, rows)`` planes in the per-tick
-    ``po`` order with the window sums and prefix snapshots appended, from
-    which the host builds each hour's ``step()`` dict and mirrors the
-    accumulators; :func:`_build_step_many_packed` packs them for the trip
-    home. Chunkings are property-tested bit-exact against each other in
-    ``tests/test_fleet_runtime.py``.
+    alignment). Per-hour outputs are ``(K, rows)`` planes in
+    :func:`_chunk_planes` order, from which the host builds each hour's
+    ``step()`` dict and mirrors the accumulators; :func:`_pack_chunk` packs
+    them for the trip home. Chunkings are property-tested bit-exact against
+    each other in ``tests/test_fleet_runtime.py``.
     """
 
     def step_many(arrays, policy, fc, fsm, ssm_h, t, routing, ring,
@@ -451,18 +445,18 @@ def _build_step_many(
         # Ring writes are the HOST's job (its replay loop updates the numpy
         # ring twins); the device carry is the small vectors only.
         seq_out = (dcum, month_vol, vpn_pref, cci_pref, pred_live)
-        # Per-hour outputs as separate (K, rows) planes, in the per-tick po
-        # order with the window sums appended. Assembling them for the trip
-        # home is the caller's job: FleetRuntime packs them into two
-        # buffers (_build_step_many_packed), the gateway masks and fetches
-        # them over its slot axis.
-        planes = (ys_t[0], ys_t[1], vpn_t, cci_t, d_pair)
+        # Per-hour outputs as separate (K, rows) planes in _chunk_planes
+        # order. Packing them for the trip home is the caller's job
+        # (_pack_chunk). The prefix snapshots ride home too: they ARE the
+        # host replay (snap[k] = prefix before hour t+k, the ring-write
+        # values), so the host adopts them instead of re-accumulating K
+        # columns itself.
+        named = dict(x=ys_t[0], state=ys_t[1], vpn_t=vpn_t, cci_t=cci_t,
+                     d_pair=d_pair, r_vpn=r_vpn, r_cci=r_cci,
+                     snap_v=snap_v, snap_c=snap_c)
         if pred_source == "live":
-            planes = planes + (ys_t[2],)
-        # The prefix snapshots ride home too: they ARE the host replay
-        # (snap[k] = prefix before hour t+k, the ring-write values), so the
-        # host adopts them instead of re-accumulating K columns itself.
-        planes = planes + (r_vpn, r_cci, snap_v, snap_c)
+            named["pred"] = ys_t[2]
+        planes = tuple(named[n] for n in _chunk_planes(pred_source))
         drain_vec = None
         if obs and drain:
             drain_vec = flatten_ring(ring)
@@ -472,52 +466,142 @@ def _build_step_many(
     return step_many
 
 
-def _packed_planes(pred_source: Optional[str], obs: bool) -> Tuple[str, ...]:
-    """The float64 planes of :func:`_build_step_many_packed`'s ``vals``, in
-    order: the ones the host reads on every call, then the live forecast
-    (the host adopts its last hour) and ``d_pair`` (the observer's only)."""
+def _chunk_planes(pred_source: Optional[str]) -> Tuple[str, ...]:
+    """The names of :func:`_build_step_many`'s ``planes``, in its order."""
+    return ("x", "state", "vpn_t", "cci_t", "d_pair") + (
+        ("pred",) if pred_source == "live" else ()
+    ) + ("r_vpn", "r_cci", "snap_v", "snap_c")
+
+
+def _packed_planes(pred_source: Optional[str], d_pair: bool) -> Tuple[str, ...]:
+    """The float64 planes a stream's ``vals`` carries, in order: the ones
+    the host reads on every call, then the live forecast (the host adopts
+    its last hour) and ``d_pair`` (the observer's, and the gateway's
+    billing)."""
     names = ("vpn_t", "cci_t", "r_vpn", "r_cci", "snap_v", "snap_c")
     if pred_source == "live":
         names += ("pred",)
-    if obs:
+    if d_pair:
         names += ("d_pair",)
     return names
+
+
+def _pack_chunk(planes: dict, seq, names: Sequence[str]):
+    """A chunk's outputs packed for ONE trip home, on the device: on the
+    chip each device array is its own blocking D2H copy, whose fixed cost
+    is well above that of its bytes. Two buffers, from ``planes`` (a dict by
+    :func:`_chunk_planes` name; the gateway vmaps over its slot axis):
+
+    * ``dec``: int8 ``(2, K, M)``, the ``x`` and FSM ``state`` planes (0/1
+      and OFF/WAITING/ON, exact in int8; the builder emits them as float64);
+    * ``vals``: one flat float64 vector, the ``names`` planes in order
+      (``(K, M)`` each, ``d_pair`` ``(K, P)``), then the accumulators
+      ``dcum``, ``month_vol`` (``(P,)``), ``vpn_pref``, ``cci_pref`` (``(M,)``).
+
+    Both are exact, so every value is the builder's bits;
+    :func:`_unpack_chunk` is the host-side inverse.
+    """
+    dec = jnp.stack([planes["x"], planes["state"]]).astype(jnp.int8)
+    vals = jnp.concatenate([planes[n].ravel() for n in names] + list(seq[:4]))
+    return dec, vals
+
+
+def _unpack_chunk(dec: np.ndarray, vals: np.ndarray, names: Sequence[str],
+                  K: int, M: int, P: int):
+    """The fetched ``(dec, vals)`` of :func:`_pack_chunk` as ``(planes,
+    accumulators)``: a dict of ``(..., K, rows)`` views (``x``/``state`` int8,
+    the ``names`` planes float64) and the four accumulators as a list of
+    views. Any leading axes (the gateway's slots) are kept."""
+    lead = vals.shape[:-1]
+    shapes = [(K, P) if n == "d_pair" else (K, M) for n in names]
+    shapes += [(P,), (P,), (M,), (M,)]
+    views, o = [], 0
+    for s in shapes:
+        n = math.prod(s)
+        views.append(vals[..., o:o + n].reshape(lead + s))
+        o += n
+    planes = dict(zip(names, views), x=dec[..., 0, :, :],
+                  state=dec[..., 1, :, :])
+    return planes, views[len(names):]
+
+
+def _fetch(arrays) -> list:
+    """Every D2H copy of a call, all started before the first is awaited."""
+    for a in arrays:
+        a.copy_to_host_async()
+    return [np.asarray(a) for a in arrays]
+
+
+def _step_out(planes: dict) -> Dict[str, np.ndarray]:
+    """The stepping outputs from unpacked ``(..., K, rows)`` planes, in
+    ``(..., rows, K)`` layout (:meth:`FleetRuntime.run`'s)."""
+    x, vpn_t, cci_t = planes["x"].astype(np.int64), planes["vpn_t"], planes["cci_t"]
+    out = dict(x=x, state=planes["state"].astype(np.int64),
+               r_vpn=planes["r_vpn"], r_cci=planes["r_cci"], vpn_cost=vpn_t,
+               cci_cost=cci_t, cost=np.where(x == 1, cci_t, vpn_t))
+    return {k: v.swapaxes(-1, -2) for k, v in out.items()}
+
+
+def _ring_reads(t: np.ndarray, h: np.ndarray, rings, outs) -> None:
+    """Gather a chunk's pre-chunk window reads from host ring twins:
+    ``out[s, k, m]`` (each of ``outs`` an ``(S, K, M)`` segment of the
+    caller's H2D block) is the prefix at hour ``t[s] + k - h[s, m]`` in the
+    hour-major ``(S, hbuf, M)`` ring, slot 0 before hour 0. In-chunk
+    positions (``k >= h``, so all ``k >= hbuf``, zero-filled) are stale;
+    the device replaces them from its snapshots. Flat indices: one base
+    ``((t - h) % hbuf)*M + row``, then a broadcast ``+M`` a later hour with
+    a single wrap fix-up (slots advance together)."""
+    S, hbuf, M = rings[0].shape
+    K = outs[0].shape[1]
+    Kw, span = min(K, hbuf), hbuf * M
+    rows = np.arange(M)
+    flat = ((t[:, None] - h) % hbuf * M + rows)[:, None, :] + np.arange(
+        0, Kw * M, M)[:, None]                                    # (S, Kw, M)
+    np.subtract(flat, span, out=flat, where=flat >= span)
+    if min(t.tolist()) < hbuf:   # early stream: hours before 0 clip to slot 0
+        flat = np.where(
+            (t[:, None] + np.arange(Kw))[:, :, None] < h[:, None, :], rows, flat
+        )
+    if S > 1:   # index the flattened (S, hbuf, M) rings; slot 0 adds nothing
+        flat += np.arange(0, S * span, span)[:, None, None]
+    for ring, out in zip(rings, outs):
+        np.take(ring.reshape(-1), flat, out=out[:, :Kw])
+        if K > Kw:
+            out[:, Kw:] = 0.0
+
+
+def _ring_commit(t: np.ndarray, rings, snaps, accs, fetched_accs) -> None:
+    """Mirror a chunk's device carry into the host twins: the last
+    ``min(K, hbuf)`` prefix snapshots ``(S, K, M)`` (``snap[k]`` is the
+    prefix BEFORE hour ``t+k``) into the ``(S, hbuf, M)`` rings at
+    ``(t + k) % hbuf``, and the fetched accumulators into ``accs``. With
+    ``K > hbuf`` the earlier snapshots would only be rewritten."""
+    S, hbuf, M = rings[0].shape
+    K = snaps[0].shape[1]
+    w = min(K, hbuf)
+    at = (t[:, None] + np.arange(K - w, K)) % hbuf                # (S, w)
+    if S > 1:   # rows of the flattened (S * hbuf, M) rings
+        at += np.arange(0, S * hbuf, hbuf)[:, None]
+    for ring, snap in zip(rings, snaps):
+        ring.reshape(S * hbuf, M)[at.ravel()] = snap[:, K - w:].reshape(S * w, M)
+    for host, got in zip(accs, fetched_accs):
+        host[...] = got
 
 
 def _build_step_many_packed(
     topology: bool, pred_source: Optional[str], endo: bool,
     obs: bool = False, drain: bool = False, K: int = 1,
 ):
-    """:func:`_build_step_many` with its outputs packed for ONE trip home.
-
-    On the chip every device array is its own blocking D2H transfer, with a
-    fixed cost per array well above the cost of its bytes, so the chunk's
-    planes and accumulators come home as two buffers instead of thirteen:
-
-    * ``dec``: int8 ``(2, K, M)``, the ``x`` and FSM ``state`` planes (0/1
-      and OFF/WAITING/ON, exact in int8; the builder emits them as float64);
-    * ``vals``: one flat float64 vector, the :func:`_packed_planes` in
-      order (``(K, M)`` each, ``d_pair`` ``(K, P)``) followed by the four
-      float64 accumulators ``dcum``, ``month_vol`` (``(P,)``), ``vpn_pref``
-      and ``cci_pref`` (``(M,)``) the host mirrors.
-
-    Planes nobody reads on the host stay on the device. The device carry
-    (``seq`` included, which stays resident) and the drained metrics ring
-    are returned as the builder returns them. Concatenation and the int8
-    round trip are exact, so every value is the builder's bits.
-    """
+    """:func:`_build_step_many` with this variant's :func:`_packed_planes`
+    packed for ONE trip home (:func:`_pack_chunk`); the device carry and the
+    drained metrics ring are returned as the builder returns them."""
     step = _build_step_many(topology, pred_source, endo, obs, drain, K)
     names = _packed_planes(pred_source, obs)
-
-    built = ("x", "state", "vpn_t", "cci_t", "d_pair") + (
-        ("pred",) if pred_source == "live" else ()
-    ) + ("r_vpn", "r_cci", "snap_v", "snap_c")      # the builder's order
+    built = _chunk_planes(pred_source)
 
     def step_many_packed(*args):
         fsm, ssm_h, t, ring, seq, planes, drain_vec = step(*args)
-        p = dict(zip(built, planes))
-        dec = jnp.stack([p["x"], p["state"]]).astype(jnp.int8)
-        vals = jnp.concatenate([p[n].ravel() for n in names] + list(seq[:4]))
+        dec, vals = _pack_chunk(dict(zip(built, planes)), seq, names)
         return fsm, ssm_h, t, ring, seq, dec, vals, drain_vec
 
     return step_many_packed
@@ -701,8 +785,7 @@ class FleetRuntime:
             self.n_demand_rows = (
                 self.arrays.n_pairs if self.topology else self.n_rows
             )
-            self._h_np = np.asarray(self.arrays.toggle.h, np.int64)
-            self._rows_idx = np.arange(self.n_rows)
+            self._h_np = np.asarray(self.arrays.toggle.h, np.int64)[None]
 
             if obs is not None and obs is not False:
                 from repro.obs.observer import FleetObserver, ObsConfig
@@ -781,10 +864,8 @@ class FleetRuntime:
     def _device_seq(self):
         """The device-resident twin of the host's sequential float64 block
         (tier cums, window prefixes, live forecast), built lazily and kept
-        across chunks. The (M, Hbuf) window RINGS deliberately stay host-only
-        — the chunked step reads them through a host gather packed into the
-        H2D block (see :func:`_build_step_many`), so the device never pays
-        ring-sized memory traffic. Invalidated by ``reset()``."""
+        across chunks; the window rings stay host-only (see
+        :func:`_build_step_many`). Invalidated by ``reset()``."""
         if self._dev_seq is None:
             st = self._state
             with jax.enable_x64():
@@ -924,43 +1005,23 @@ class FleetRuntime:
                 jax.block_until_ready((dec, vals))
             with span("fleet.step.fetch"):
                 # Every D2H copy of the call: the int8 decisions, the packed
-                # float64 vector and, on drain calls, the metrics ring. All
-                # are started before the first is awaited.
-                home = (dec, vals, drain_vec) if drain else (dec, vals)
-                for a in home:
-                    a.copy_to_host_async()
-                fetched = [np.asarray(a) for a in home]
+                # float64 vector and, on drain calls, the metrics ring.
+                fetched = _fetch((dec, vals, drain_vec) if drain else (dec, vals))
                 count("fleet.step.d2h_arrays", len(fetched))
                 count("fleet.step.h2d_bytes", block.nbytes)
                 count("fleet.step.d2h_bytes", sum(a.nbytes for a in fetched))
 
             with span("fleet.step.mirror"):
-                M = self.n_rows
-                names = _packed_planes(self.pred_source, self.obs is not None)
-                shapes = [(K, P) if n == "d_pair" else (K, M) for n in names]
-                shapes += [(P,), (P,), (M,), (M,)]
-                views, o = [], 0
-                for s in shapes:
-                    n = math.prod(s)
-                    views.append(fetched[1][o:o + n].reshape(s))
-                    o += n
-                p = dict(zip(names, views))                 # (K, rows) planes
-                x = fetched[0][0].astype(np.int64)
-                state = fetched[0][1].astype(np.int64)
-                vpn_t, cci_t = p["vpn_t"], p["cci_t"]
-                snap_v, snap_c = p["snap_v"], p["snap_c"]
-
-                # Mirror the device's sequential scans into the host
-                # accumulators. ``snap[k]`` is the prefix BEFORE hour t+k
-                # (the ring-snapshot / exclusive-prefix convention); the seq
-                # carry holds the post-chunk accumulators. K > hbuf: only
-                # the last hbuf slots survive, earlier ones are rewritten.
-                tks = t + np.arange(K)
-                w = min(K, self.hbuf)
-                st.ring_vpn[tks[K - w:] % self.hbuf] = snap_v[K - w:K]
-                st.ring_cci[tks[K - w:] % self.hbuf] = snap_c[K - w:K]
-                (st.dcum[:], st.month_vol[:],
-                 st.vpn_pref[:], st.cci_pref[:]) = views[len(names):]
+                p, accs = _unpack_chunk(
+                    fetched[0], fetched[1],
+                    _packed_planes(self.pred_source, self.obs is not None),
+                    K, self.n_rows, P,
+                )                                           # (K, rows) planes
+                _ring_commit(
+                    np.asarray([t]), (st.ring_vpn[None], st.ring_cci[None]),
+                    (p["snap_v"][None], p["snap_c"][None]),
+                    (st.dcum, st.month_vol, st.vpn_pref, st.cci_pref), accs,
+                )
                 self._state = st._replace(
                     t=t + K, fsm=fsm, ssm_h=ssm_h, t_dev=t_dev,
                     pred_live=(
@@ -969,15 +1030,7 @@ class FleetRuntime:
                     ),
                     metrics=ring,
                 )
-                out = {                        # (rows, K): run()'s layout
-                    "x": x.T,
-                    "state": state.T,
-                    "r_vpn": p["r_vpn"].T,
-                    "r_cci": p["r_cci"].T,
-                    "vpn_cost": vpn_t.T,
-                    "cci_cost": cci_t.T,
-                    "cost": np.where(x == 1, cci_t, vpn_t).T,
-                }
+                out = _step_out(p)
         if self.obs is not None:
             self.obs.record_chunk(
                 t,
@@ -990,52 +1043,22 @@ class FleetRuntime:
 
     def _pack(self, st: RuntimeState, d: np.ndarray, cci_demand_block):
         """The chunk's single flat H2D block (see :func:`_build_step_many`):
-        the (rows, K) demand [and CCI demand], then the pre-chunk window
-        reads as two (K, rows) planes."""
-        t = st.t
+        the (rows, K) demand [and CCI demand], raveled in their native order
+        (the device transposes where it fuses anyway), then the pre-chunk
+        window reads as two (K, rows) planes, gathered in place by
+        :func:`_ring_reads`."""
         M, P = self.n_rows, self.n_demand_rows
         K = d.shape[1]
-        endo = cci_demand_block is not None
-        # Pre-chunk window reads, gathered from the HOST ring twins and
-        # packed into the chunk's single H2D block (see _build_step_many —
-        # the device never holds the rings). In-chunk positions (lo >= t)
-        # gather stale slots here; the device replaces them from its
-        # prefix-scan snapshots.
-        # Flat indices into the hour-major (hbuf, M) ring: slot*M + row. One
-        # per-row base ((t - h) % hbuf)*M + row, then each later hour is a
-        # broadcast +M with a single wrap fixup (slots advance together).
-        # Hours with t+k >= hbuf*? only matter while k < h[m] <= hbuf-1, so
-        # one subtract covers every live wrap.
-        Kw = min(K, self.hbuf)
-        flat = ((t - self._h_np) % self.hbuf) * M + self._rows_idx   # (M,)
-        flat = flat[None, :] + (np.arange(Kw) * M)[:, None]          # (Kw, M)
-        np.subtract(flat, self.hbuf * M, out=flat,
-                    where=flat >= self.hbuf * M)
-        if t < self.hbuf:   # early stream: hours before 0 clip to slot 0
-            flat = np.where(
-                (t + np.arange(Kw))[:, None] < self._h_np[None, :],
-                self._rows_idx[None, :], flat,
-            )
-        # One flat H2D buffer, every segment written contiguously: the
-        # demand matrix ravels in its native (rows, K) order (the device
-        # transposes it where it fuses anyway) and the ring gathers land
-        # straight in place — no transposed copies, no concatenate.
-        nd = (2 if endo else 1) * K * P
+        nd = (1 if cci_demand_block is None else 2) * K * P
         block = np.empty(nd + 2 * K * M)
         block[:K * P] = d.ravel()
-        if endo:
+        if cci_demand_block is not None:
             c = np.asarray(cci_demand_block, np.float64)
             assert c.shape == d.shape, (c.shape, d.shape)
             block[K * P:nd] = c.ravel()
-        np.take(st.ring_vpn.reshape(-1), flat,
-                out=block[nd:nd + Kw * M].reshape(Kw, M))
-        np.take(st.ring_cci.reshape(-1), flat,
-                out=block[nd + K * M:nd + (K + Kw) * M].reshape(Kw, M))
-        if K > Kw:
-            # k >= hbuf is always in-chunk (h <= hbuf-1): the device
-            # replaces these from its snapshots, so any value works.
-            block[nd + Kw * M:nd + K * M] = 0.0
-            block[nd + (K + Kw) * M:] = 0.0
+        pre = block[nd:].reshape(2, 1, K, M)
+        _ring_reads(np.asarray([st.t]), self._h_np,
+                    (st.ring_vpn[None], st.ring_cci[None]), pre)
         return block
 
     def run(self, demand, *, cci_demand=None) -> Dict[str, np.ndarray]:

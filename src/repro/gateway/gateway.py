@@ -3,10 +3,15 @@
 One :class:`FleetGateway` serves many independent tenants — each with its
 own :class:`~repro.fleet.topology.TopologySpec`/routing (or fleet spec),
 policy pytree, billing calendar, horizon, and demand stream — from shared
-capacity-bucketed state pools. Per gateway hour, each non-empty bucket
-costs exactly ONE jitted dispatch: the standalone step of
-:func:`repro.fleet.runtime._build_step_many`, ``jax.vmap``-ed over the
-pool's leading slot axis and masked by an alive bitmap. Membership churn (join,
+capacity-bucketed state pools. Per gateway chunk, each non-empty bucket
+costs exactly ONE jitted dispatch: the runtime's packed chunk
+(:func:`repro.fleet.runtime._build_step_many`, then
+:func:`~repro.fleet.runtime._pack_chunk`), ``jax.vmap``-ed over the pool's
+leading slot axis and masked by an alive bitmap, so a bucket's call brings
+home two arrays (three on a drain call), as ``FleetRuntime.step_many``
+does. The host side of the round trip (ring reads into the flat H2D block,
+fetch, ring and accumulator mirror, output dict) is the runtime's own
+helpers over that slot axis. Membership churn (join,
 leave, grow/shrink across buckets, re-route) is pure operand traffic —
 ``.at[slot].set`` writes into fixed-shape pools — so a bucket shape
 compiles once, ever.
@@ -20,7 +25,7 @@ resolve through the same :func:`~repro.fleet.runtime.resolve_runtime_operands`
 path, (b) padding is provably inert (:mod:`repro.gateway.pool`), and
 (c) the sequential reductions (prefixes, month boundaries, tier state)
 run on the device in the standalone order, with the prefix rings mirrored
-host-side exactly as the standalone runtime mirrors them.
+host-side by the standalone runtime's own mirror.
 
 Billing stays host-side per tenant (float64 accumulators, surviving
 bucket moves via a carry), metrics ride the PR-6 device ring with a tenant
@@ -44,9 +49,16 @@ import jax.numpy as jnp
 
 from repro.core.planner import collective_mode
 from repro.fleet.routing import as_routing_plan, padded_operand_np
+from repro.fleet import runtime
 from repro.fleet.runtime import (
     RuntimeConfig,
-    _build_step_many,
+    _fetch,
+    _pack_chunk,
+    _packed_planes,
+    _ring_commit,
+    _ring_reads,
+    _step_out,
+    _unpack_chunk,
     resolve_runtime_operands,
 )
 from repro.obs.metrics import (
@@ -56,8 +68,14 @@ from repro.obs.metrics import (
     reset_ring_slot,
 )
 from repro.obs.monitors import ContractViolation, TenantSLOMonitor
+from repro.obs.profile import count
 
 from .pool import BucketKey, bucket_key_for, pack_tenant, set_slot
+
+
+# The float64 planes a bucket brings home: every plane the host reads, with
+# d_pair for per-tenant billed GB (no pooled tenant has a live forecaster).
+_PLANES = _packed_planes(None, d_pair=True)
 
 
 class AdmissionError(RuntimeError):
@@ -207,9 +225,8 @@ class _Bucket:
         return self.n_slots - len(self.free)
 
     def device_seq(self):
-        # The (slots, Hbuf, M) window rings stay host-only — the chunked
-        # mega-tick reads them through a host gather packed into the H2D
-        # block (see repro.fleet.runtime._build_step_many).
+        # The (slots, Hbuf, M) window rings stay host-only: the gateway runs
+        # the runtime's packed chunk over its slot axis (runtime._ring_reads).
         if self._dev_seq is None:
             with jax.enable_x64():
                 self._dev_seq = (
@@ -221,6 +238,12 @@ class _Bucket:
                     )),
                 )
         return self._dev_seq
+
+    def totals(self, s: int) -> Dict[str, np.float64]:
+        """Slot ``s``'s host float64 billing: realized $, VPN/CCI
+        counterfactual $, billed GB."""
+        return {"realized": self.bill_real[s].sum(), "vpn": self.bill_vpn[s].sum(),
+                "cci": self.bill_cci[s].sum(), "gb": self.gb[s].sum()}
 
     def ensure_T(self, T: int) -> None:
         cur = self.demand.shape[2]
@@ -416,9 +439,12 @@ class FleetGateway:
         )
         fn = self._compiled.get(ck)
         if fn is None:
-            chunk = _build_step_many(
+            # Looked up through the module at build time, as the runtime's
+            # _step_many_fn does.
+            chunk = runtime._build_step_many(
                 key.topology, key.pred_source, False, self._obs, drain, K
             )
+            built = runtime._chunk_planes(key.pred_source)
             edges = self._edges
 
             def mega(arrays, policy, fsm, ssm_h, t, routing, ring,
@@ -431,9 +457,13 @@ class FleetGateway:
                     arrays, policy, fsm, ssm_h, t, routing, ring,
                     hpm, seq, blocks
                 )
-                # Alive-bitmap mask over each (n_slots, K, rows) plane.
-                ys = tuple(p * alive[:, None, None] for p in ys)
-                return fsm, ssm_h, t1, ring, seq, ys, dv
+                # Alive-bitmap mask over each (n_slots, K, rows) plane,
+                # before packing; the accumulators stay unmasked.
+                ys = {n: p * alive[:, None, None] for n, p in zip(built, ys)}
+                dec, vals = jax.vmap(
+                    lambda pl, sq: _pack_chunk(pl, sq, _PLANES)
+                )(ys, seq)
+                return fsm, ssm_h, t1, ring, seq, dec, vals, dv
 
             fn = jax.jit(
                 mega, donate_argnums=(6, 9) if self._obs else (9,)
@@ -494,76 +524,39 @@ class FleetGateway:
     def _tick_bucket_many(self, key, b, K, drain, collect, outs,
                           finished) -> None:
         M, P = key.rows_cap, key.pairs_cap
-        hb = key.hbuf_cap
         cols = np.minimum(
             b.t[:, None] + np.arange(K)[None, :], b.demand.shape[2] - 1
         )
-        demand_cols = np.take_along_axis(
-            b.demand, cols[:, None, :], axis=2
-        )                                                # (n_slots, P, K)
-        # Pre-chunk window reads from the HOST ring twins, packed into the
-        # same flat H2D block the standalone runtime uses (the device never
-        # holds the rings; in-chunk positions are replaced on device from
-        # its prefix-scan snapshots). Flat per-slot indices into the
-        # hour-major (hb, M) ring: slot*M + row, one wrap fixup (per-slot
-        # clocks differ, so the early-stream clip applies per slot).
-        Kw = min(K, hb)
-        rows = np.arange(M)
-        flat = ((b.t[:, None] - b.h_np) % hb) * M + rows[None, :]
-        flat = (
-            flat[:, None, :] + (np.arange(Kw) * M)[None, :, None]
-        )                                                # (n_slots, Kw, M)
-        np.subtract(flat, hb * M, out=flat, where=flat >= hb * M)
-        early = (
-            b.t[:, None, None] + np.arange(Kw)[None, :, None]
-        ) < b.h_np[:, None, :]
-        flat = np.where(early, rows[None, None, :], flat)
-        pre_v = np.take_along_axis(
-            b.ring_vpn.reshape(b.n_slots, -1),
-            flat.reshape(b.n_slots, -1), axis=1,
-        )
-        pre_c = np.take_along_axis(
-            b.ring_cci.reshape(b.n_slots, -1),
-            flat.reshape(b.n_slots, -1), axis=1,
-        )
+        # The runtime's flat H2D block, one row per slot: demand, then the
+        # pre-chunk window reads gathered from the host ring twins.
         nd = K * P
-        blocks = np.zeros((b.n_slots, nd + 2 * K * M))
-        blocks[:, :nd] = demand_cols.reshape(b.n_slots, nd)
-        blocks[:, nd:nd + Kw * M] = pre_v
-        blocks[:, nd + K * M:nd + (K + Kw) * M] = pre_c
+        blocks = np.empty((b.n_slots, nd + 2 * K * M))
+        blocks[:, :nd] = np.take_along_axis(
+            b.demand, cols[:, None, :], axis=2
+        ).reshape(b.n_slots, nd)                        # native (P, K) order
+        pre = blocks[:, nd:].reshape(b.n_slots, 2, K, M)
+        _ring_reads(b.t, b.h_np, (b.ring_vpn, b.ring_cci),
+                    (pre[:, 0], pre[:, 1]))
         blocks *= b.alive[:, None]
 
         fn = self._mega_many_fn(key, b.n_slots, drain, K)
         hpm_dev, seq = b.device_seq()
         with jax.enable_x64():
-            b.fsm, b.ssm_h, b.t_dev, b.ring, seq, ys, dv = fn(
+            b.fsm, b.ssm_h, b.t_dev, b.ring, seq, dec, vals, dv = fn(
                 b.arrays, b.policy, b.fsm, b.ssm_h, b.t_dev,
                 b.routing, b.ring, b.alive_dev, hpm_dev, seq,
                 jax.device_put(blocks),
             )
         b._dev_seq = (hpm_dev, seq)
-        it = iter(ys)                                    # (n_slots, K, rows)
-        nxt = lambda: np.asarray(next(it))
-        x, state, vpn_t, cci_t, d_pair = nxt(), nxt(), nxt(), nxt(), nxt()
-        if key.pred_source == "live":
-            next(it)   # pred plane — the SSM carry rides the device seq
-        r_vpn, r_cci = nxt(), nxt()
-        snap_v, snap_c = nxt(), nxt()                    # prefix BEFORE t+k
+        fetched = _fetch((dec, vals, dv) if drain else (dec, vals))
+        count("gateway.tick.d2h_arrays", len(fetched))
+        p, accs = _unpack_chunk(fetched[0], fetched[1], _PLANES, K, M, P)
 
-        # Mirror the device's sequential carry: the prefix snapshots are
-        # the ring values (snap[k] is the prefix BEFORE hour t+k; dead slots
-        # are alive-masked to zero), the seq carry the post-chunk
-        # accumulators. One summation order — the device's — for every
-        # chunking, as in the standalone runtime.
-        tks = b.t[:, None] + np.arange(K)[None, :]       # (n_slots, K)
-        w = min(K, key.hbuf_cap)  # K > hbuf: early slots would be rewritten
-        wslots = (tks[:, K - w:] % key.hbuf_cap)[:, :, None]
-        np.put_along_axis(b.ring_vpn, wslots, snap_v[:, K - w:K], axis=1)
-        np.put_along_axis(b.ring_cci, wslots, snap_c[:, K - w:K], axis=1)
-        for host, dev in zip(
-            (b.dcum, b.month_vol, b.vpn_pref, b.cci_pref), seq[:4]
-        ):
-            host[...] = np.asarray(dev)
+        # Mirror the device's sequential carry as the standalone runtime
+        # does (dead slots' snapshots are alive-masked to zero).
+        _ring_commit(b.t, (b.ring_vpn, b.ring_cci), (p["snap_v"], p["snap_c"]),
+                     (b.dcum, b.month_vol, b.vpn_pref, b.cci_pref), accs)
+        x, vpn_t, cci_t = p["x"], p["vpn_t"], p["cci_t"]
         # Billing accumulates hour by hour on the host: np.add.accumulate
         # is a strictly sequential left fold, so seeding it with the carry
         # gives the same bits for any chunking.
@@ -571,32 +564,22 @@ class FleetGateway:
             np.concatenate([carry[:, None], cols], axis=1), axis=1
         )
         b.bill_real[...] = seeded(
-            b.bill_real, np.where(x == 1.0, cci_t, vpn_t)
+            b.bill_real, np.where(x == 1, cci_t, vpn_t)
         )[:, K]
         b.bill_vpn[...] = seeded(b.bill_vpn, vpn_t)[:, K]
         b.bill_cci[...] = seeded(b.bill_cci, cci_t)[:, K]
-        b.gb[...] = seeded(b.gb, d_pair)[:, K]
+        b.gb[...] = seeded(b.gb, p["d_pair"])[:, K]
 
-        vecs = np.asarray(dv) if drain else None
+        out = _step_out(p) if collect else None      # (n_slots, rows, K)
         for s, name in enumerate(b.slots):
             if name is None:
                 continue
-            m = int(b.m[s])
             if collect:
-                xs = x[s, :, :m].astype(np.int64).T      # (m, K) stacked
-                outs[name] = {
-                    "x": xs,
-                    "state": state[s, :, :m].astype(np.int64).T,
-                    "r_vpn": r_vpn[s, :, :m].T,
-                    "r_cci": r_cci[s, :, :m].T,
-                    "vpn_cost": vpn_t[s, :, :m].T,
-                    "cci_cost": cci_t[s, :, :m].T,
-                    "cost": np.where(
-                        xs == 1, cci_t[s, :, :m].T, vpn_t[s, :, :m].T
-                    ),
-                }
+                m = int(b.m[s])
+                outs[name] = {k: v[s, :m] for k, v in out.items()}
             if drain:
-                self._drain_slot(name, b, s, vecs[s].copy(), int(b.t[s]) + K)
+                self._drain_slot(name, b, s, fetched[2][s].copy(),
+                                 int(b.t[s]) + K)
             if b.t[s] + K >= b.horizon[s]:
                 finished.append(name)
         b.t += K
@@ -615,14 +598,8 @@ class FleetGateway:
             n_bins=self.hist_bins, n_tiers=b.key.n_tiers,
         )
         self._drained[name].append(dm)
-        host_totals = {
-            "realized": b.bill_real[s].sum(),
-            "vpn": b.bill_vpn[s].sum(),
-            "cci": b.bill_cci[s].sum(),
-            "gb": b.gb[s].sum(),
-        }
         self.violations.extend(
-            self._monitors[name].on_drain(hour, dm, host_totals=host_totals)
+            self._monitors[name].on_drain(hour, dm, host_totals=b.totals(s))
         )
 
     def _flush_slot(self, name, b, s) -> None:
@@ -649,10 +626,8 @@ class FleetGateway:
         s = handle.slot
         self._flush_slot(name, b, s)
         carry = self._billing_carry[name]
-        carry["realized"] += b.bill_real[s].sum()
-        carry["vpn"] += b.bill_vpn[s].sum()
-        carry["cci"] += b.bill_cci[s].sum()
-        carry["gb"] += b.gb[s].sum()
+        for k, v in b.totals(s).items():
+            carry[k] += v
         b.clear_slot(s)
         handle.status, handle.bucket, handle.slot = status, None, None
         self._drain_admission_queue()
@@ -765,10 +740,8 @@ class FleetGateway:
         handle = self._tenants[name]
         if handle.status == "active":
             b, s = self._bucket_of(handle), handle.slot
-            totals["realized"] += b.bill_real[s].sum()
-            totals["vpn"] += b.bill_vpn[s].sum()
-            totals["cci"] += b.bill_cci[s].sum()
-            totals["gb"] += b.gb[s].sum()
+            for k, v in b.totals(s).items():
+                totals[k] += v
         return {k: float(v) for k, v in totals.items()}
 
     def metrics(self, name: str) -> List[DrainedMetrics]:

@@ -534,6 +534,42 @@ def test_step_many_out_is_the_builders_planes(variant):
                 assert _bits(seen["drain"]) == _bits(drain_vec)
 
 
+@pytest.mark.parametrize("case, t, K", [
+    ("early_stream", (2, 6), 5),
+    ("wrap", (13, 30), 7),
+    ("k_over_hbuf", (20, 41), 11),
+])
+def test_ring_reads_match_a_per_hour_loop(case, t, K):
+    """The shared ring read (``runtime._ring_reads``) over two slots on
+    different clocks equals a plain loop over every (slot, hour, row):
+    the prefix at hour ``t + k - h`` from slot ``(t + k - h) % hbuf``,
+    slot 0 before hour 0, and zero where ``k >= hbuf``."""
+    from repro.fleet import runtime
+
+    rng = np.random.default_rng(len(case))
+    S, hbuf, M = 2, 8, 5
+    t = np.asarray(t)
+    h = rng.integers(1, hbuf, size=(S, M))
+    rings = tuple(rng.normal(size=(S, hbuf, M)) for _ in range(2))
+    outs = tuple(np.full((S, K, M), np.nan) for _ in range(2))
+    runtime._ring_reads(t, h, rings, outs)
+    for ring, out in zip(rings, outs):
+        want = np.empty((S, K, M))
+        for s in range(S):
+            for k in range(K):
+                for m in range(M):
+                    hour = t[s] + k - h[s, m]
+                    want[s, k, m] = (
+                        0.0 if k >= hbuf else ring[s, max(hour, 0) % hbuf, m]
+                    )
+        np.testing.assert_array_equal(out, want, err_msg=case)
+    if case == "early_stream":
+        assert (t[:, None, None] + np.arange(K)[:, None] < h[:, None, :]).any()
+    else:
+        assert (t >= hbuf).all()
+    assert (K > hbuf) == (case == "k_over_hbuf")
+
+
 def test_replay_single_segment_is_plan_topology():
     """A one-entry schedule must reproduce plan_topology bit-for-bit (the
     replay oracle degenerates to the offline planner)."""

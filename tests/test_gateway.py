@@ -435,3 +435,30 @@ def test_sync_groups_and_tenant_labels():
     assert m is not None and m.group(0) == label
     # Untagged labels are unchanged (the pre-gateway format).
     assert sync_domain_label(3, "compressed") == "syncdom_g3_compressed"
+
+
+@pytest.mark.parametrize("drain", [False, True], ids=["plain", "drain"])
+def test_tick_many_fetches_two_arrays_a_bucket(drain):
+    """A bucket's ``tick_many`` call brings home the runtime's two packed
+    buffers (int8 decisions, one float64 vector), and the drained metrics
+    ring as a third on a drain call: ``gateway.tick.d2h_arrays`` once a
+    bucket a call."""
+    import time
+
+    from repro.obs import profile
+
+    K = 6
+    gw = FleetGateway(GatewayConfig(slots_per_bucket=4,
+                                    cadence=K if drain else 2 * K))
+    for i in range(2):
+        sc = build_fleet_scenario(2, horizon=24, seed=i)
+        gw.join(f"t{i}", TenantSpec(spec=sc.fleet, demand=sc.demand))
+    profile.force_recording(True)
+    try:
+        t0 = time.perf_counter()
+        gw.tick_many(K, collect=False)
+        _, counts = profile.recorded(t0)
+    finally:
+        profile.force_recording(None)
+    got = [n for name, _, n in counts if name == "gateway.tick.d2h_arrays"]
+    assert got == [3 if drain else 2] * gw.n_buckets

@@ -247,6 +247,31 @@ def prefix_sum(x: jax.Array) -> jax.Array:
     return jnp.moveaxis(ys, 0, -1)
 
 
+def lag_rows(x: jax.Array, h, axis: int = 0) -> jax.Array:
+    """``x`` read at ``max(0, t - h)`` along ``axis``, one lag ``h`` per row.
+
+    ``h`` holds one lag for each position of the other axes (a scalar under
+    ``vmap``, or ``(M,)`` for an hour-major ``(K, M)`` block). No gather:
+    the TPU's gather emitter moves one element at a time (~86 M elements/s
+    on v5e, four of them were 85% of the offline plan's device time), so
+    the lag is composed from ``n.bit_length()`` static shifts by ``2**b``,
+    each kept where bit ``b`` of ``h`` is set. A shift by ``s`` fills with
+    the row's first element, which every shift keeps in place, so the
+    composition of shifts ``a`` and ``b`` reads ``max(0, t - a - b)``.
+    Only selects, no arithmetic: the values are ``x``'s own bits.
+    """
+    n = x.shape[axis]
+    xt = jnp.moveaxis(x, axis, 0)
+    h = jnp.clip(jnp.asarray(h), 0, n)
+    first = xt[:1]
+    for b in range(n.bit_length()):
+        s = 1 << b
+        fill = jnp.broadcast_to(first, (min(s, n),) + xt.shape[1:])
+        lagged = jnp.concatenate([fill, xt[:n - s]]) if s < n else fill
+        xt = jnp.where((h >> b) & 1 == 1, lagged, xt)
+    return jnp.moveaxis(xt, 0, axis)
+
+
 def monthly_cumsum(demand: jax.Array, hours_per_month: int) -> jax.Array:
     """Exclusive within-month cumulative volume along the LAST axis.
 

@@ -13,7 +13,9 @@ engines end-to-end (both policies' decisions and the cost series), plus the
 live-SSM forecast mode, the endogenous-demand planner, and the collective
 actuation path (int8 vs hierarchical selected by link modes).
 """
+import dataclasses
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -532,6 +534,88 @@ def test_step_many_out_is_the_builders_planes(variant):
             assert ("drain" in seen) == drain
             if drain:
                 assert _bits(seen["drain"]) == _bits(drain_vec)
+
+
+@pytest.mark.parametrize("K", [1, 24])
+def test_chunk_window_sums_compile_without_a_gather(K):
+    """The reactive chunk program reads its in-chunk window starts by
+    static shifts: no ``gather`` of the compiled program comes from the
+    ``window_sums`` scope (its ``op_name``), whose other ops are there.
+    Compiled for the CPU, where a gather keeps the scope of the call it
+    was inlined from; nothing runs."""
+    from repro.fleet import runtime
+
+    rt, demand, _ = _variant_runtime("plain")
+    st = rt._state
+    fn = jax.jit(runtime._build_step_many(
+        rt.topology, rt.pred_source, False, False, False, K))
+    with jax.enable_x64():
+        text = fn.lower(
+            rt.arrays, rt.policy, rt._fc, st.fsm, st.ssm_h, st.t_dev,
+            st.routing, st.metrics, rt._obs_edges, rt._hpm_dev,
+            rt._device_seq(), jax.device_put(rt._pack(st, demand[:, :K], None)),
+        ).compile().as_text()
+    assert "/window_sums/" in text
+    scopes = [n for line in text.splitlines() if " gather(" in line
+              for n in re.findall(r'op_name="([^"]*)"', line)]
+    assert not [n for n in scopes if "/window_sums/" in n], scopes
+
+
+@pytest.mark.parametrize("K", [1, 24, 168])
+def test_step_many_window_starts_in_and_before_the_chunk_bit_exact(K):
+    """Windows of 24 to 336 h streamed from hour 0 in blocks of K: at
+    t0 = 0 every row's window starts inside the chunk; later, at K=168,
+    rows with h <= k read it inside the chunk and the rest before it.
+    Each chunk's window sums equal, bit for bit, a gather at the clamped
+    window start over the chunk's own snapshot planes and ring reads; the
+    stream's decisions equal the offline plan's, and its VPN window sums
+    the offline window sums over its own cost series. (The CCI series is
+    held to its snapshots only: on XLA:CPU the chunk's CCI prefix can add
+    a last bit that its emitted ``cci_cost`` lacks, a mul-add contraction
+    in the pricing that comes before the window reads.)"""
+    from repro.fleet import runtime
+
+    rng = np.random.default_rng(K)
+    hs = np.array([24, 25, 72, 167, 168, 169, 336])
+    fleet = fleet_from_params(
+        [dataclasses.replace(_random_params(rng), h=int(h)) for h in hs])
+    M, T = hs.size, 3 * 168
+    demand = _random_demand(rng, M, T)
+    rt = FleetRuntime(fleet)
+    fn = jax.jit(runtime._build_step_many(False, None, False, False, False, K))
+    ks = np.arange(K)[:, None]
+    outs, read_in, read_before = [], False, False
+    for t0 in range(0, T, K):
+        st, d = rt._state, demand[:, t0:t0 + K]
+        block = rt._pack(st, d, None)
+        with jax.enable_x64():
+            planes = fn(rt.arrays, rt.policy, rt._fc, st.fsm, st.ssm_h,
+                        st.t_dev, st.routing, st.metrics, rt._obs_edges,
+                        rt._hpm_dev, rt._device_seq(), jax.device_put(block))[5]
+        r_vpn, r_cci, snap_v, snap_c = map(np.asarray, planes[-4:])
+        lo = np.maximum(0, t0 + ks - hs[None, :])                 # (K, M)
+        in_chunk = lo >= t0
+        jj = np.clip(lo - t0, 0, K - 1)
+        for r, snap, pre in ((r_vpn, snap_v, block[K * M:2 * K * M]),
+                             (r_cci, snap_c, block[2 * K * M:3 * K * M])):
+            want = snap - np.where(in_chunk, np.take_along_axis(snap, jj, 0),
+                                   pre.reshape(K, M))
+            assert _bits(r) == _bits(want), (K, t0)
+        read_in |= bool(t0 and in_chunk.any())
+        read_before |= bool((~in_chunk).any())
+        outs.append(rt.step_many(d))
+    assert read_before and read_in == (K == 168)
+    got = {f: np.concatenate([o[f] for o in outs], axis=1)
+           for f in ("x", "state", "r_vpn", "vpn_cost", "cci_cost")}
+
+    plan = plan_fleet(fleet, demand)
+    np.testing.assert_array_equal(got["x"], np.asarray(plan["x"]))
+    np.testing.assert_array_equal(got["state"], np.asarray(plan["state"]))
+    with jax.enable_x64():
+        pol = make_policy("reactive", fleet.stack(jnp.float64).toggle)
+        ref = jax.jit(jax.vmap(policy_scan))(
+            pol, jnp.asarray(got["vpn_cost"]), jnp.asarray(got["cci_cost"]))
+    assert _bits(got["r_vpn"]) == _bits(np.asarray(ref["r_vpn"]))
 
 
 @pytest.mark.parametrize("case, t, K", [

@@ -241,3 +241,45 @@ def test_scan_accepts_traceable_toggle_params():
         np.testing.assert_array_equal(
             np.asarray(xs[i]), run_togglecci(p, d, costs=costs).x
         )
+
+
+# ---------------------------------------------------------------------------
+# Window reads without a gather
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("form", ["per_row", "per_column"])
+@pytest.mark.parametrize("n", [1, 24, 168, 1000])
+def test_lag_rows_is_take_along_axis_at_the_clamped_lag(n, form):
+    """``lag_rows`` reads ``x[max(0, t - h)]`` bit for bit, one ``h`` per
+    row, for lags of none, one, inside the axis, its last index, its whole
+    length and past it: a scalar ``h`` under ``vmap`` (the offline plan)
+    and a per-column ``h`` on an hour-major block (the chunk), under jit."""
+    from repro.core.costmodel import lag_rows
+
+    h = np.array([0, 1, 72, 336, n - 1, n, n + 5], np.int32)
+    with jax.enable_x64():
+        x = jnp.asarray(np.random.default_rng(n).standard_normal((h.size, n)))
+        lo = np.maximum(0, np.arange(n)[None, :] - h[:, None])
+        want = jnp.take_along_axis(x, jnp.asarray(lo), axis=1)
+        if form == "per_row":
+            got = jax.jit(jax.vmap(lag_rows))(x, h)
+        else:
+            got = jax.jit(lambda a, b: lag_rows(a, b, axis=0))(x.T, h).T
+    assert got.dtype == jnp.float64 and got.shape == want.shape
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+def test_plan_window_sums_lower_without_a_gather():
+    """The offline plan's window sums at fleet size (2048 links x a year,
+    float64) lower to static slices and selects: no ``gather``, which the
+    TPU runs one element at a time. Lowering only; nothing runs."""
+    from repro.core.togglecci import window_sums
+
+    with jax.enable_x64():
+        text = jax.jit(jax.vmap(window_sums)).lower(
+            jax.ShapeDtypeStruct((2048, 8760), jnp.float64),
+            jax.ShapeDtypeStruct((2048,), jnp.int32),
+        ).as_text()
+    assert "gather" not in text
+    assert "f64" in text
